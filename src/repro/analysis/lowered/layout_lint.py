@@ -17,6 +17,12 @@ autotuning one that can't win:
   sublane rule has deliberately NO full-dim exemption: a (1, 1) VMEM
   block still burns a full (8, 128) tile, which is exactly the bug the
   SSD per-head scalars had before moving to SMEM.
+* **whole-array SMEM** — an SMEM scalar operand is one block spanning
+  its whole array, indexed by ``program_id`` inside the kernel. The TPU
+  compiler applies the tiling rule to SMEM blocks too, so a (1, 1)
+  block of an (N, 1) array is refused at compile time (the old
+  ``flash_decode`` and ``ssd_scan`` scalar layouts, which interpret
+  mode ran without complaint).
 * **coverage** — grid × block tiles the padded array exactly (a
   remainder row means the index map re-reads or drops elements).
 * **VMEM footprint** — double-buffered operand+output blocks plus
@@ -60,7 +66,13 @@ def _tile_bytes(shape, dtype) -> int:
 def _check_operand(name: str, op: OperandLayout) -> List[str]:
     msgs: List[str] = []
     if op.memory != "vmem":
-        return msgs                      # SMEM scalars are tile-exempt
+        # SMEM scalars are tile-exempt as whole arrays only
+        if tuple(op.block) != tuple(op.shape):
+            msgs.append(
+                f"{name}: SMEM block {op.block} does not span its array "
+                f"{op.shape} — block SMEM scalars whole and index them "
+                f"by program_id")
+        return msgs
     if len(op.block) != len(op.shape):
         return [f"{name}: block rank {len(op.block)} != array rank "
                 f"{len(op.shape)}"]

@@ -171,6 +171,7 @@ def round_surfaces(flt: Sequence[str]) -> List[Dict]:
     from repro.federated.simulator import (ROUND_DONATE_ARGNUMS,
                                            _round_flops,
                                            make_round_program)
+    from repro.launch.mesh import make_mesh
     from repro.launch.sharding import batch_shardings, params_shardings
     from repro.models import transformer as T
 
@@ -220,7 +221,7 @@ def round_surfaces(flt: Sequence[str]) -> List[Dict]:
                          "chips": chips}
             try:
                 _require_devices(MIN_DEVICES)
-                mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+                mesh = make_mesh(mesh_shape, ("data", "model"))
                 in_sh = (params_shardings(mesh, args[0]),
                          params_shardings(mesh, args[1]),
                          batch_shardings(mesh, args[2]),

@@ -42,7 +42,7 @@ def pretrained_base(spec: ExperimentSpec):
 
         cfg = spec.build_cfg()
         params = T.init_params(cfg, jax.random.PRNGKey(spec.seed),
-                               jnp.float32)
+                               spec.base_dtype())
         if spec.homogeneous_init:
             # identical-layer init: the functional-homogeneity regime of
             # large pretrained LLMs that DGLG/DBLF assume
@@ -86,6 +86,7 @@ def run_experiment(spec: ExperimentSpec, *,
                                    seed=spec.seed)
     from repro.launch.mesh import resolve_mesh
     runner = FederatedRunner(cfg, spec.fed_config(), data, params=params,
+                             dtype=spec.base_dtype(),
                              mesh=resolve_mesh(spec.mesh))
     t0 = time.time()
     logs = runner.run(round_progress)

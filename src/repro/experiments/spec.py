@@ -2,9 +2,10 @@
 
 A spec composes everything needed to reproduce an experiment:
 
-* **model** — ``arch`` (registry id), ``full`` (cluster-scale config vs
+* **model** — ``arch`` (registry id), ``full`` (published widths vs
   reduced), ``reduced`` (ReducedSpec field overrides), ``layers`` (depth
-  override for reduced runs);
+  override, for reduced and full runs alike: a full config cut to
+  fewer layers keeps every published width);
 * **data** — ``n_clients``, ``alpha`` (Dirichlet non-IID), ``noise``,
   ``seed`` (shared by data generation and the federated engine);
 * **federated** — every knob in :class:`repro.federated.FedConfig`,
@@ -45,8 +46,8 @@ _REDUCED_KEYS = frozenset(f.name for f in dataclasses.fields(ReducedSpec))
 class ExperimentSpec:
     # ---- model -------------------------------------------------------
     arch: str = "llama2-7b-proxy"
-    full: bool = False                       # cluster-scale config
-    layers: Optional[int] = None             # depth override (reduced)
+    full: bool = False                       # published widths
+    layers: Optional[int] = None             # depth override
     reduced: Optional[Dict[str, int]] = None  # ReducedSpec overrides
     kernel_backend: str = "auto"             # pallas | reference | auto
     # ---- data --------------------------------------------------------
@@ -198,19 +199,28 @@ class ExperimentSpec:
         return FedConfig(**{f: getattr(self, f) for f in FED_FIELDS})
 
     def build_cfg(self):
-        """Model config for this spec (same semantics as the old
-        ``launch/train.py`` path: reduce unless ``full``, then apply the
-        depth override). The spec's ``kernel_backend`` rides on the
-        config so every layer — including DEVFT submodels built from it
-        by ``dataclasses.replace`` — dispatches consistently."""
+        """Model config for this spec: reduce unless ``full``, then
+        apply the depth override (a full config keeps its published
+        widths and is cut in depth only). The spec's ``kernel_backend``
+        rides on the config so every layer — including DEVFT submodels
+        built from it by ``dataclasses.replace`` — dispatches
+        consistently."""
         cfg = get_config(self.arch)
         if not self.full:
             rspec = ReducedSpec(**self.reduced) if self.reduced \
                 else ReducedSpec()
             cfg = reduce_config(cfg, rspec)
-            if self.layers:
-                cfg = dataclasses.replace(cfg, n_layers=self.layers)
+        if self.layers:
+            cfg = dataclasses.replace(cfg, n_layers=self.layers)
         return dataclasses.replace(cfg, kernel_backend=self.kernel_backend)
+
+    def base_dtype(self):
+        """dtype of the frozen base weights: the config's own
+        (``bfloat16`` for every registered arch) at published widths,
+        float32 for the reduced CPU runs the golden round logs pin."""
+        import jax.numpy as jnp
+        return jnp.dtype(self.build_cfg().dtype) if self.full \
+            else jnp.dtype(jnp.float32)
 
 
 def _digest(obj) -> str:

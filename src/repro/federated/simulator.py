@@ -22,7 +22,9 @@ stacked client-batch arrays' leading sampled-client axis over the
 per-round LoRA buffers to the round program. ``mesh=None``
 (default) runs the same trace on the default device; trajectories are
 identical either way — that parity is pinned by
-``tests/test_mesh_round.py``.
+``tests/test_mesh_round.py``. On a mesh of more than one device the
+model runs the reference path (``mesh_kernel_backend``): GSPMD cannot
+partition the Pallas kernels.
 
 Heterogeneous clients (DESIGN.md §3): ``FedConfig.population`` names a
 device fleet (``repro.federated.heterogeneity``); each round the engine
@@ -177,6 +179,25 @@ def make_round_program(strategy, run_state, sub_cfg, n_sample, *,
     return round_fn, aux
 
 
+def mesh_kernel_backend(cfg, mesh):
+    """The config a round on ``mesh`` runs with. GSPMD cannot partition
+    a Pallas (Mosaic) kernel — the TPU compiler refuses one inside a
+    program sharded over more than one device — so there ``auto``
+    takes the reference path, and an explicit ``pallas`` is an error
+    until the kernels are wrapped in ``shard_map``. One device (no mesh,
+    or a 1x1 mesh) keeps the config as it is."""
+    if mesh is None or mesh.size == 1:
+        return cfg
+    from repro.kernels.dispatch import KernelBackend, canonical
+    backend = canonical(cfg.kernel_backend)
+    if backend == KernelBackend.PALLAS.value:
+        raise ValueError(
+            f"kernel_backend='pallas' on a {mesh.size}-device mesh: Pallas "
+            f"kernels cannot be partitioned by GSPMD; use 'auto' or "
+            f"'reference'")
+    return dataclasses.replace(cfg, kernel_backend="reference")
+
+
 def count_params(tree) -> int:
     return int(sum(np.prod(l.shape) for l in jax.tree.leaves(tree)))
 
@@ -212,6 +233,7 @@ class FederatedRunner:
 
     def __init__(self, cfg, fed: FedConfig, data: FederatedData, *,
                  dtype=jnp.float32, params=None, mesh=None):
+        cfg = mesh_kernel_backend(cfg, mesh)
         self.cfg = cfg
         self.fed = fed
         self.data = data

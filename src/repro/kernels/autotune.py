@@ -52,6 +52,11 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 #: env var overriding the default on-disk cache location
 CACHE_ENV = "REPRO_TUNING_CACHE"
 
+#: the default cache: a file of the package, so a checkout carries its
+#: winners and nothing outside it steers the kernels
+DEFAULT_CACHE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "tuning.json")
+
 #: swept values per tunable knob, per kernel. Candidate order is
 #: deterministic (itertools.product over this table), default first.
 TUNABLES: Dict[str, Dict[str, Tuple[int, ...]]] = {
@@ -75,11 +80,7 @@ DEFAULTS: Dict[str, Dict[str, int]] = {
 
 
 def default_cache_path() -> str:
-    env = os.environ.get(CACHE_ENV)
-    if env:
-        return env
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "repro-kernels", "tuning.json")
+    return os.environ.get(CACHE_ENV) or DEFAULT_CACHE
 
 
 def shape_key(args: Sequence) -> str:
@@ -390,7 +391,7 @@ def main(argv=None) -> int:
                     help="limit shape cases per kernel (CI smoke)")
     ap.add_argument("--cache", default=None,
                     help=f"cache path (default ${CACHE_ENV} or "
-                         f"~/.cache/repro-kernels/tuning.json)")
+                         f"src/repro/kernels/tuning.json)")
     ap.add_argument("--verify-dispatch", action="store_true",
                     help="after the sweep, assert dispatch resolves "
                          "every stored entry to its tuned config")

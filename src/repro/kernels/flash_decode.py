@@ -15,7 +15,10 @@ sublane extent (a (1, hd) q block would waste a full (8, 128) tile per
 head).
 
 Raggedness: each slot's live prefix length arrives as ``kv_valid_len``
-(B,) — a (B, 1) SMEM operand inside the kernel. Dead cache slots are
+(B,) — one whole-array SMEM operand inside the kernel, indexed by the
+slot's grid position (the TPU compiler refuses a (1, 1) block of a
+(B, 1) array: a block's last two dims must be tile multiples or span
+the array). Dead cache slots are
 masked out of the softmax *probability* (not just the logit): a slot
 with ``valid == 0`` keeps a zero denominator and emits exactly zeros,
 matching ``attend``'s fully-masked-row rule rather than averaging
@@ -73,7 +76,7 @@ def decode_layout(b: int, h: int, hkv: int, cap: int, hd: int,
                                name),
             "v": OperandLayout((b, hkv, cap_p, vd), (1, 1, block_k, vd),
                                name),
-            "kv_valid_len": OperandLayout((b, 1), (1, 1), "int32",
+            "kv_valid_len": OperandLayout((b,), (b,), "int32",
                                           memory="smem"),
         },
         outputs={"o": OperandLayout((b, hkv, rep_p, vd),
@@ -85,6 +88,7 @@ def decode_layout(b: int, h: int, hkv: int, cap: int, hd: int,
 
 def _decode_kernel(valid_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, scale: float, block_k: int):
+    bi = pl.program_id(0)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -94,7 +98,7 @@ def _decode_kernel(valid_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    valid = valid_ref[0, 0]                              # this slot's length
+    valid = valid_ref[bi]                                # this slot's length
     k_start = ki * block_k
 
     # skip cache blocks entirely past this slot's live prefix
@@ -166,15 +170,14 @@ def flash_decode_bhrd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if cap_p != cap:
         pad = ((0, 0), (0, 0), (0, cap_p - cap), (0, 0))
         kt, vt = jnp.pad(kt, pad), jnp.pad(vt, pad)
-    valid = kv_valid_len.reshape(b, 1).astype(jnp.int32)
+    valid = kv_valid_len.reshape(b).astype(jnp.int32)
 
     kernel = functools.partial(_decode_kernel, scale=scale, block_k=block_k)
     out = pl.pallas_call(
         kernel,
         grid=lay.grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b_, h_, k_: (b_, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),     # whole (B,) array
             pl.BlockSpec((1, 1, rep_p, hd), lambda b_, h_, k_: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, block_k, hd),
                          lambda b_, h_, k_: (b_, h_, k_, 0)),

@@ -33,17 +33,19 @@ def ssd_layout(bsz: int, h: int, s: int, p: int, n: int,
     """Declared block layout of ``ssd_scan_bhsp`` at one shape (the
     wrapper derives grid/padding/blocks from this; L003 lints it).
 
-    The per-head decay/skip scalars a and d ride as (h, 1) arrays with
-    (1, 1) SMEM blocks — they are scalars inside the kernel body, and a
-    (1, 1, 1, 1) VMEM block would burn a full (8, 128) tile per head
-    and fail sublane alignment. The chunk is capped to the
+    The per-head decay/skip scalars a and d ride as whole (h,) float32
+    arrays in SMEM, indexed by the head's grid position: they are
+    scalars inside the kernel body, a (1, 1, 1, 1) VMEM block would
+    burn a full (8, 128) tile per head, and the TPU compiler refuses a
+    (1, 1) block of an (h, 1) array (a block's last two dims must be
+    tile multiples or span the array). The chunk is capped to the
     granule-rounded sequence so ragged sequences pad instead of
     asserting."""
     g = sublane(dtype)
     chunk = tile_block_cap(chunk, s, g)
     s_pad = round_up(s, chunk)
     name = jnp.dtype(dtype).name
-    scalar = OperandLayout((h, 1), (1, 1), name, memory="smem")
+    scalar = OperandLayout((h,), (h,), "float32", memory="smem")
     return BlockLayout(
         kernel="ssd_scan",
         grid=(bsz, h, s_pad // chunk),
@@ -62,6 +64,7 @@ def ssd_layout(bsz: int, h: int, s: int, p: int, n: int,
 
 def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref,
                 state_ref, *, chunk: int):
+    hi = pl.program_id(1)
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -72,20 +75,27 @@ def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref,
     dt = dt_ref[0, 0].astype(jnp.float32)        # (c, 1)
     bb = b_ref[0, 0].astype(jnp.float32)         # (c, N)
     cc = c_ref[0, 0].astype(jnp.float32)         # (c, N)
-    a = a_ref[0, 0]                              # (1, 1) SMEM -> scalar
-    dd = d_ref[0, 0]
+    a = a_ref[hi]                                # SMEM scalar of this head
+    dd = d_ref[hi]
 
     da = dt * a                                  # (c,1), negative
-    cum = jnp.cumsum(da, axis=0)                 # (c,1)
-    # ---- intra-chunk quadratic term (MXU) ----------------------------
-    diff = cum - cum.T                           # (c, c) = cum_i - cum_j
+    # prefix sums and row views by masked reductions: Mosaic lowers
+    # neither cumsum nor the transpose of a (c, 1) column
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    eye = ii == jj
+    cum_row = jnp.sum(jnp.where(ii <= jj, da, 0.0), axis=0,
+                      keepdims=True)             # (1,c) = cum_j
+    cum = jnp.sum(jnp.where(eye, cum_row, 0.0), axis=1,
+                  keepdims=True)                 # (c,1) = cum_i
+    dt_row = jnp.sum(jnp.where(eye, dt, 0.0), axis=0, keepdims=True)
+    # ---- intra-chunk quadratic term (MXU) ----------------------------
+    diff = cum - cum_row                         # (c, c) = cum_i - cum_j
     l_mat = jnp.where(ii >= jj, jnp.exp(diff), 0.0)
     scores = jax.lax.dot_general(
         cc, bb, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)      # (c, c)
-    w = scores * l_mat * dt.T                    # weight by dt_j
+    w = scores * l_mat * dt_row                  # weight by dt_j
     y = jax.lax.dot(w, x, preferred_element_type=jnp.float32)
     # ---- inter-chunk: contract cached state --------------------------
     y += jnp.exp(cum) * jax.lax.dot_general(
@@ -94,8 +104,9 @@ def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref,
     y += x * dd
     y_ref[0, 0] = y.astype(y_ref.dtype)
     # ---- state update -------------------------------------------------
-    total = jnp.exp(cum[-1:])                    # (1,1)
-    decay_to_end = jnp.exp(cum[-1:] - cum)       # (c,1)
+    cum_end = jnp.sum(da, axis=0, keepdims=True)  # (1,1) = cum_{c-1}
+    total = jnp.exp(cum_end)
+    decay_to_end = jnp.exp(cum_end - cum)        # (c,1)
     xw = x * (dt * decay_to_end)                 # (c,P)
     state_ref[...] = state_ref[...] * total + jax.lax.dot_general(
         xw, bb, (((0,), (0,)), ((), ())),
@@ -119,9 +130,10 @@ def ssd_scan_bhsp(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     if s_pad != s:
         pad = ((0, 0), (0, 0), (0, s_pad - s), (0, 0))
         x, dt2, b, c = (jnp.pad(t, pad) for t in (x, dt2, b, c))
-    # per-head scalars as (H, 1) SMEM operands — see ssd_layout
-    a2 = a.reshape(h, 1)
-    d2 = d.reshape(h, 1)
+    # per-head scalars as whole (H,) f32 SMEM operands — see ssd_layout
+    # (the kernel computes in f32, so the cast changes no value)
+    a2 = a.reshape(h).astype(jnp.float32)
+    d2 = d.reshape(h).astype(jnp.float32)
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
     y = pl.pallas_call(
@@ -132,10 +144,8 @@ def ssd_scan_bhsp(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
             pl.BlockSpec((1, 1, chunk, 1), lambda b_, h_, c_: (b_, h_, c_, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda b_, h_, c_: (b_, h_, c_, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda b_, h_, c_: (b_, h_, c_, 0)),
-            pl.BlockSpec((1, 1), lambda b_, h_, c_: (h_, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda b_, h_, c_: (h_, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),     # whole (H,) a
+            pl.BlockSpec(memory_space=pltpu.SMEM),     # whole (H,) d
         ],
         out_specs=pl.BlockSpec((1, 1, chunk, p),
                                lambda b_, h_, c_: (b_, h_, c_, 0)),

@@ -1,94 +1,40 @@
-"""Process-level computation environment setup for the CLI entry
-points (bench, serve, autotune).
+"""Process-level setup for the entry points (``chip_smoke.py``, train,
+serve, the benchmarks, the autotuner): JAX's persistent compilation
+cache.
 
-One call, before the first jax computation::
+One call, before the first compile::
 
     from repro.launch.env import setup_environment
-    setup_environment()                       # platform-appropriate defaults
-    setup_environment("gpu", cpu_cores=8)     # explicit
+    setup_environment()
 
-It concentrates the environment knobs every compiled-path run wants —
-the XLA GPU latency-hiding / async-collective flags, the x64 toggle and
-CPU host-device pinning — so bench and serve runs measure the tuned
-configuration rather than whatever the shell happened to export. jax is
-imported lazily inside the function: ``XLA_FLAGS`` and host-device
-counts only take effect when set before the jax backend initializes, so
-this module must be importable without pulling jax in.
-
-Idempotent and append-only on ``XLA_FLAGS``: flags the caller already
-exported are kept and never duplicated.
+Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+leaves it alone: the cache goes there and nowhere else. Otherwise the
+cache goes to ``.jax_cache/`` at the root of the checkout. The path is
+part of each entry's key, so it is fixed: a second run from the same
+checkout finds the first run's programs and skips their compiles.
+``.gitignore`` lists the directory.
 """
 from __future__ import annotations
 
 import os
-from typing import Dict, Optional
+from typing import Dict
 
-# gpu_performance_tips defaults. Only applied when the process is
-# actually headed for a GPU backend: a CPU/TPU-only XLA build does NOT
-# register the xla_gpu flag set and aborts at backend init on unknown
-# XLA_FLAGS — so "harmless elsewhere" is false and must be gated.
-_GPU_XLA_FLAGS = (
-    "--xla_gpu_enable_triton_softmax_fusion=true",
-    "--xla_gpu_triton_gemm_any=True",
-    "--xla_gpu_enable_async_collectives=true",
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-    "--xla_gpu_enable_highest_priority_async_stream=true",
-)
+#: the cache directory used when ``JAX_COMPILATION_CACHE_DIR`` is unset
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
-def _gpu_build() -> bool:
-    """Whether this process can plausibly initialize a GPU backend (the
-    CUDA plugin is installed or jaxlib was built with CUDA). Checked
-    WITHOUT importing jax — XLA_FLAGS must be decided first."""
-    import importlib.util
-    return any(importlib.util.find_spec(m) is not None
-               for m in ("jax_cuda12_plugin", "jax_cuda11_plugin",
-                         "jaxlib.cuda_extension"))
-
-
-def _append_xla_flags(flags) -> str:
-    """Merge ``flags`` into ``XLA_FLAGS`` without duplicating any flag
-    (keyed on the ``--name`` part, so an explicit user value wins)."""
-    existing = os.environ.get("XLA_FLAGS", "").split()
-    have = {f.split("=", 1)[0] for f in existing}
-    merged = existing + [f for f in flags
-                         if f.split("=", 1)[0] not in have]
-    value = " ".join(merged)
-    if value:
-        os.environ["XLA_FLAGS"] = value
-    return value
-
-
-def setup_environment(platform: Optional[str] = None, *,
-                      x64: bool = False,
-                      cpu_cores: Optional[int] = None) -> Dict[str, object]:
-    """Configure the process for a compiled-path run.
-
-    ``platform`` pins ``jax_platform_name`` (None = leave jax's own
-    autodetection alone); ``x64`` flips the default float width;
-    ``cpu_cores`` sets ``--xla_force_host_platform_device_count`` (the
-    host-platform device pin — only meaningful before backend init).
-    Returns a summary dict of what was applied, for logging.
-    """
-    applied: Dict[str, object] = {}
-    if cpu_cores is not None:
-        n = max(1, min(int(cpu_cores), os.cpu_count() or 1))
-        applied["cpu_cores"] = n
-        _append_xla_flags(
-            (f"--xla_force_host_platform_device_count={n}",))
-    if platform == "gpu" or (platform is None and _gpu_build()):
-        _append_xla_flags(_GPU_XLA_FLAGS)
-    applied["xla_flags"] = os.environ.get("XLA_FLAGS", "")
-
+def setup_environment() -> Dict[str, str]:
+    """Point JAX's persistent compilation cache at its directory (see
+    the module docstring). Returns ``{"compilation_cache_dir": path}``
+    for logging."""
     import jax
 
-    if platform is not None:
-        jax.config.update("jax_platform_name", platform)
-        applied["platform"] = platform
-    # honor a pre-exported JAX_ENABLE_X64 even when the caller passed
-    # the default, mirroring jax's own env convention
-    x64 = bool(x64 or os.environ.get("JAX_ENABLE_X64", "") in
-               ("1", "true", "True"))
-    jax.config.update("jax_enable_x64", x64)
-    applied["x64"] = x64
-    return applied
+    path = os.environ.get(CACHE_ENV)
+    if not path:
+        path = CHECKOUT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    return {"compilation_cache_dir": path}

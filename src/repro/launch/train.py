@@ -51,13 +51,14 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--arch", default=None, choices=ALL_ARCH_IDS)
     ap.add_argument("--full", dest="full", action="store_const",
                     const=True, default=None,
-                    help="use the full (cluster-scale) config")
+                    help="use the published widths (cut in depth by "
+                         "--layers)")
     ap.add_argument("--no-full", dest="full", action="store_const",
                     const=False,
                     help="force the reduced config (override a full "
                          "spec file)")
     ap.add_argument("--layers", type=int, default=None,
-                    help="override depth (reduced runs)")
+                    help="override depth (reduced and full runs)")
     ap.add_argument("--kernel-backend", default=None,
                     choices=list(BACKENDS),
                     help="model hot-path kernels: pallas | reference | "
@@ -149,6 +150,9 @@ def main(argv=None):
     if args.dump_spec:
         print(spec.to_json())
         return 0
+
+    from repro.launch.env import setup_environment
+    setup_environment()
 
     def progress(log):
         print(f"round {log.round:3d} stage {log.stage} cap {log.capacity:3d}"

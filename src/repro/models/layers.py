@@ -12,9 +12,10 @@ Kernel backends: every layer reads ``cfg.kernel_backend`` and routes its
 hot ops through ``repro.kernels.dispatch`` — ``attend`` to the Pallas
 flash-attention kernel, ``_proj`` (frozen weight + LoRA) to the fused
 ``lora_matmul`` kernel. The ``reference`` backend is the inline jnp math
-below, unchanged, so golden round logs stay bit-identical. Decode entry
-points pin ``reference``: single-token GEMMs are bandwidth-bound and the
-ragged-cache masking (``kv_valid_len``) is outside the kernel contract.
+below, unchanged, so golden round logs stay bit-identical. Decode
+attention over the ragged cache routes to the ``flash_decode`` kernel;
+decode projections stay jnp (single-token GEMMs, and per-slot adapters
+the fused LoRA kernel does not take).
 """
 from __future__ import annotations
 
@@ -136,12 +137,16 @@ def _flash_eligible(q, k, v, q_offset, kv_valid_len) -> bool:
 
 
 def _gqa_scores(q: jax.Array, k: jax.Array) -> jax.Array:
-    """q: (B,Sq,H,hd); k: (B,Sk,Hkv,hd) -> scores (B,Hkv,rep,Sq,Sk)."""
+    """q: (B,Sq,H,hd); k: (B,Sk,Hkv,hd) -> float32 scores
+    (B,Hkv,rep,Sq,Sk). Accumulated and returned in float32, as the
+    attention kernels do: bf16 scores of magnitude ~10 round by ~0.02,
+    which moves the softmax by a few percent."""
     b, sq, h, hd = q.shape
     hkv = k.shape[2]
     rep = h // hkv
     qg = q.reshape(b, sq, hkv, rep, hd)
-    return jnp.einsum("bqkrd,bskd->bkrqs", qg, k)
+    return jnp.einsum("bqkrd,bskd->bkrqs", qg, k,
+                      preferred_element_type=jnp.float32)
 
 
 def attend(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -168,7 +173,7 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, *,
         flash = dispatch.get_kernel("flash_attention", backend)
         return flash(q, k, v, causal=causal, window=window, scale=scale,
                      interpret=dispatch.interpret_default())
-    scores = _gqa_scores(q * scale, k).astype(jnp.float32)  # (B,Hkv,rep,Sq,Sk)
+    scores = _gqa_scores(q * scale, k)                      # (B,Hkv,rep,Sq,Sk)
 
     qpos = jnp.arange(sq) + q_offset                         # (Sq,)
     kpos = jnp.arange(sk)                                    # (Sk,)

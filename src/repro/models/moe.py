@@ -166,7 +166,6 @@ def moe_block_ep(params: dict, cfg, x: jax.Array, *, mesh,
     resharding of (E, C, d) buffers on the baseline path.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     m = cfg.moe
     tp = mesh.shape[tp_axis]
@@ -217,12 +216,12 @@ def moe_block_ep(params: dict, cfg, x: jax.Array, *, mesh,
         return y, aux
 
     shared = params.get("shared")
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(data_axes), P(), P(tp_axis), P(tp_axis), P(tp_axis),
                   None if shared is None else P()),
         out_specs=(P(data_axes), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, params["router"], params["wg"], params["wu"], params["wd"],
               shared)

@@ -11,10 +11,9 @@ windows, and zero-reset of one slot when it is recycled to a new
 request (conv/SSM state included, so recurrent families recycle too).
 
 Kernel seam: single-token decode attention routes through the
-``flash_decode`` name in ``repro.kernels.dispatch`` (reference-only
-today, like the MoE grouped-GEMM seam) — a Pallas flash-decode kernel
-for ragged caches registers under ``("flash_decode", "pallas")`` and
-every engine/serve path picks it up with no model edits. Its contract
+``flash_decode`` name in ``repro.kernels.dispatch``; the Pallas kernel
+registered under ``("flash_decode", "pallas")`` serves every
+engine/serve path on the TPU with no model edits. Its contract
 is the reference signature: ``flash_decode(q, k, v, *, kv_valid_len,
 scale=None, interpret=False)`` with ``q (B, 1, H, hd)``, cache-resident
 ``k/v (B, C, Hkv, hd)`` and ``kv_valid_len (B,)`` masking ragged slots.

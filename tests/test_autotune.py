@@ -84,6 +84,19 @@ def test_env_var_overrides_default_path(monkeypatch, tmp_path):
     assert autotune.default_cache_path() == p
 
 
+def test_default_path_is_inside_the_package(monkeypatch):
+    # the winners travel with the checkout; nothing under $HOME steers
+    # kernel resolution
+    import os
+
+    import repro.kernels
+    monkeypatch.delenv(autotune.CACHE_ENV, raising=False)
+    path = autotune.default_cache_path()
+    pkg = os.path.dirname(os.path.abspath(repro.kernels.__file__))
+    assert os.path.dirname(path) == pkg
+    assert not path.startswith(os.path.expanduser("~") + os.sep + ".cache")
+
+
 # ---------------------------------------------------------------------------
 # dispatch consultation
 # ---------------------------------------------------------------------------

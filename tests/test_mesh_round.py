@@ -81,6 +81,24 @@ def test_run_experiment_mesh_knob(tiny_setup):
         == [dataclasses.asdict(l) for l in b.logs]
 
 
+def test_multi_device_mesh_takes_the_reference_kernels(tiny_setup):
+    """GSPMD cannot partition a Pallas kernel: on a mesh of more than
+    one device ``auto`` runs the reference path and ``pallas`` is an
+    error; one device keeps the config."""
+    import types
+
+    from repro.federated.simulator import mesh_kernel_backend
+    cfg, _ = tiny_setup
+    four = types.SimpleNamespace(size=4)
+    auto = dataclasses.replace(cfg, kernel_backend="auto")
+    pallas = dataclasses.replace(cfg, kernel_backend="pallas")
+    assert mesh_kernel_backend(auto, four).kernel_backend == "reference"
+    with pytest.raises(ValueError, match="cannot be partitioned"):
+        mesh_kernel_backend(pallas, four)
+    assert mesh_kernel_backend(pallas, make_host_mesh()) is pallas
+    assert mesh_kernel_backend(pallas, None) is pallas
+
+
 def test_resolve_mesh_names():
     assert resolve_mesh(None) is None
     assert resolve_mesh("none") is None
