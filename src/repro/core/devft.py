@@ -12,6 +12,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.fusion import fuse_stack
 from repro.core.grouping import make_groups
 from repro.core.stages import StageSchedule, allocate_stack_capacities
@@ -56,7 +58,10 @@ def build_submodel(cfg, params: dict, lora: dict, capacity: int, *,
 
     ``capacity`` counts layers across all shrinkable stacks; protected
     stacks (whisper encoder) are carried over whole. ``seed`` is an int
-    or a tuple of keyed entropy (e.g. ``(base_seed, stage)``).
+    or a tuple of keyed entropy (e.g. ``(base_seed, stage)``). Each
+    stack that shrinks is grouped under the host span
+    ``repro.devft.group`` (args ``layers``, ``groups``) and fused under
+    ``repro.devft.fuse`` (args ``layers_in``, ``layers_out``).
     """
     sizes = stack_sizes(params["blocks"])
     shrinkable = {n: s for n, s in sizes.items() if n not in _PROTECTED}
@@ -74,10 +79,16 @@ def build_submodel(cfg, params: dict, lora: dict, capacity: int, *,
                               "n_layers": sizes[name]}
             continue
         lo = lora.get(name)
-        groups = make_groups(grouping, stack, lo, caps[name], seed=seed)
-        new_blocks[name] = fuse_stack(stack, groups, beta, fusion, seed=seed)
-        if lo is not None:
-            new_lora[name] = fuse_stack(lo, groups, beta, fusion, seed=seed)
+        with TraceAnnotation("repro.devft.group", layers=sizes[name],
+                             groups=caps[name]):
+            groups = make_groups(grouping, stack, lo, caps[name], seed=seed)
+        with TraceAnnotation("repro.devft.fuse", layers_in=sizes[name],
+                             layers_out=caps[name]):
+            new_blocks[name] = fuse_stack(stack, groups, beta, fusion,
+                                          seed=seed)
+            if lo is not None:
+                new_lora[name] = fuse_stack(lo, groups, beta, fusion,
+                                            seed=seed)
         plan[name] = {"groups": groups, "n_layers": sizes[name]}
 
     sub_params = dict(params)
@@ -122,7 +133,8 @@ class DevFTController:
 
     def finish_stage(self, global_lora: dict, trained_sub_lora: dict) -> dict:
         assert self._current is not None, "no stage in flight"
-        new = transfer_stage(global_lora, trained_sub_lora,
-                             self._current.plan)
+        with TraceAnnotation("repro.devft.transfer"):
+            new = transfer_stage(global_lora, trained_sub_lora,
+                                 self._current.plan)
         self._current = None
         return new

@@ -38,6 +38,9 @@ def make_local_train(cfg, *, lr_is_input: bool = True, remat: bool = False,
     from the same plan that built the mask
     (``heterogeneity.RoundPlan``/``aggregation_weights``), not from
     this traced value.
+
+    The HLO metadata names the work ``local_train``, and each step's
+    ``loss_and_grad`` and ``adamw`` inside it (``jax.named_scope``).
     """
 
     def step(carry, batch, params, lr, m=None):
@@ -47,9 +50,12 @@ def make_local_train(cfg, *, lr_is_input: bool = True, remat: bool = False,
             return loss_fn(cfg, params, lo, batch, remat=remat,
                            window=window, moe_path=moe_path, mesh=mesh)
 
-        (total, metrics), grads = jax.value_and_grad(lfn, has_aux=True)(lora)
-        new_lora, new_opt = adamw_update(grads, opt, lora, lr,
-                                         weight_decay=0.0)
+        with jax.named_scope("loss_and_grad"):
+            (total, metrics), grads = jax.value_and_grad(
+                lfn, has_aux=True)(lora)
+        with jax.named_scope("adamw"):
+            new_lora, new_opt = adamw_update(grads, opt, lora, lr,
+                                             weight_decay=0.0)
         if m is not None:
             keep = m > 0
             new_lora = jax.tree.map(
@@ -58,6 +64,7 @@ def make_local_train(cfg, *, lr_is_input: bool = True, remat: bool = False,
                 lambda n, o: jnp.where(keep, n, o), new_opt, opt)
         return (new_lora, new_opt), metrics["loss"]
 
+    @jax.named_scope("local_train")
     def local_train(params, lora, batches, lr, step_mask=None):
         opt = init_adamw(lora)
         k, b, s = batches["labels"].shape[:3]

@@ -45,6 +45,23 @@ round ``r`` computes, and eval itself runs every
 ``FedConfig.eval_every`` rounds (default 1; skipped rounds carry the
 last evaluated values forward, and the final round always evaluates).
 
+Host spans (``jax.profiler.TraceAnnotation``, on the device trace's
+clock; free when no profiler runs): ``repro.run`` (args ``rounds``,
+``method``) holds ``repro.run.prepare`` (eval batch, strategy state,
+the first round's batches), one ``repro.round`` step per round (a
+``StepTraceAnnotation``; args ``stage``, ``capacity``, ``clients``,
+``tokens``) and ``repro.run.finalize``. A round holds, in order,
+``repro.stage.enter`` (at a stage's first round; args ``stage``,
+``capacity``; DevFT's ``repro.devft.transfer``, ``repro.devft.group``
+and ``repro.devft.fuse`` nest in it), ``repro.round.plan``,
+``repro.round.place`` (arg ``bytes``), ``repro.round.dispatch``,
+``repro.eval.dispatch``, ``repro.round.host_batches`` (the prefetch;
+arg ``bytes``), ``repro.round.fetch`` (the blocking read of the round
+before's eval scalars; the last one follows the loop) and
+``repro.round.books``. Device-side, ``jax.named_scope`` marks
+``local_train`` (with ``loss_and_grad`` and ``adamw``), ``aggregate``
+and ``eval`` in the HLO metadata.
+
 Cost accounting (per paper §4.4):
 * communication — exact bytes of transmitted LoRA tensors, up + down,
   per sampled client (strategies can override the byte hooks; dropped
@@ -65,6 +82,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.data.synthetic import (
     FederatedData,
@@ -162,8 +180,9 @@ def make_round_program(strategy, run_state, sub_cfg, n_sample, *,
 
             loras, metrics = jax.vmap(per_client)(batches, masks)
             spec = LocalSpec(sub_cfg, params, lora)
-            new_lora, aux["up"] = strategy.aggregate(
-                run_state, spec, loras, n_sample, weights=weights)
+            with jax.named_scope("aggregate"):
+                new_lora, aux["up"] = strategy.aggregate(
+                    run_state, spec, loras, n_sample, weights=weights)
             return new_lora, metrics
     else:
         def round_fn(params, lora, batches, lr):
@@ -172,8 +191,9 @@ def make_round_program(strategy, run_state, sub_cfg, n_sample, *,
 
             loras, metrics = jax.vmap(per_client)(batches)
             spec = LocalSpec(sub_cfg, params, lora)
-            new_lora, aux["up"] = strategy.aggregate(
-                run_state, spec, loras, n_sample)
+            with jax.named_scope("aggregate"):
+                new_lora, aux["up"] = strategy.aggregate(
+                    run_state, spec, loras, n_sample)
             return new_lora, metrics
 
     return round_fn, aux
@@ -327,7 +347,8 @@ class FederatedRunner:
         if key not in self._eval_fn_cache:
             @jax.jit
             def ev(params, lora, batch):
-                _, m = T.loss_fn(sub_cfg, params, lora, batch)
+                with jax.named_scope("eval"):
+                    _, m = T.loss_fn(sub_cfg, params, lora, batch)
                 return m["loss"], m["acc"]
 
             self._eval_fn_cache[key] = ev
@@ -407,99 +428,124 @@ class FederatedRunner:
 
     # ---- main loop ------------------------------------------------------
     def run(self, progress: Optional[Callable] = None) -> List[RoundLog]:
-        fed, strat = self.fed, self.strategy
+        fed = self.fed
         if fed.eval_every < 1:
             raise ValueError(f"eval_every must be >= 1, got "
                              f"{fed.eval_every}")
+        with TraceAnnotation("repro.run", rounds=fed.rounds,
+                             method=fed.method):
+            return self._run(progress)
+
+    def _run(self, progress: Optional[Callable]) -> List[RoundLog]:
+        fed, strat = self.fed, self.strategy
         logs: List[RoundLog] = []
         n_sample = self._n_sample
-        eval_batch = self._place_batches(
-            self.data.eval_batch(16, fed.seq))
-
-        state = strat.init_state(self.params, self.lora)
-        self._run_state = state
-        rounds = list(strat.build_rounds(state))
-        n_rounds = len(rounds)
+        with TraceAnnotation("repro.run.prepare"):
+            eval_batch = self._place_batches(
+                self.data.eval_batch(16, fed.seq))
+            state = strat.init_state(self.params, self.lora)
+            self._run_state = state
+            rounds = list(strat.build_rounds(state))
+            n_rounds = len(rounds)
+            clients, batches = self._host_batches(0) if n_rounds \
+                else (None, None)
         stage_prev = -1
         pending: Optional[RoundLog] = None
         ev_loss = ev_acc = None          # device scalars, carried forward
         sim_time = 0.0                   # cumulative virtual wall-clock
-        clients, batches = self._host_batches(0) if n_rounds \
-            else (None, None)
         for rnd, (stage, capn) in enumerate(rounds):
-            stage_entry = stage != stage_prev
-            if stage_entry:
-                strat.on_stage(state, stage)
-                stage_prev = stage
-            spec = strat.local_spec(state)
-            plan = self._plan(spec, clients, rnd)
-            if not self._hetero and (plan.n_dropped
-                                     or plan.total_steps
-                                     != n_sample * fed.k_local):
-                # defense in depth: the legacy program ignores the plan,
-                # so a plan that deviates from full uniform work must
-                # never reach it (the _hetero gate should have engaged)
-                raise RuntimeError(
-                    "internal: round plan deviates from full work but "
-                    "the legacy round program is compiled "
-                    f"(policy={fed.straggler_policy!r}, "
-                    f"deadline_factor={fed.deadline_factor})")
-            sim_time += plan.duration_s
+            with StepTraceAnnotation("repro.round", step_num=rnd,
+                                     stage=stage, capacity=capn,
+                                     clients=n_sample) as round_span:
+                stage_entry = stage != stage_prev
+                if stage_entry:
+                    with TraceAnnotation("repro.stage.enter", stage=stage,
+                                         capacity=capn):
+                        strat.on_stage(state, stage)
+                    stage_prev = stage
+                with TraceAnnotation("repro.round.plan"):
+                    spec = strat.local_spec(state)
+                    plan = self._plan(spec, clients, rnd)
+                    if not self._hetero and (
+                            plan.n_dropped
+                            or plan.total_steps != n_sample * fed.k_local):
+                        # defense in depth: the legacy program ignores
+                        # the plan, so a plan that deviates from full
+                        # uniform work must never reach it (the _hetero
+                        # gate should have engaged)
+                        raise RuntimeError(
+                            "internal: round plan deviates from full work "
+                            "but the legacy round program is compiled "
+                            f"(policy={fed.straggler_policy!r}, "
+                            f"deadline_factor={fed.deadline_factor})")
+                round_span.set_metadata(
+                    tokens=plan.total_steps * fed.local_batch * fed.seq)
+                sim_time += plan.duration_s
 
-            # ---- local training + aggregation (one device program) ----
-            lr = strat.client_lr(stage)
-            dev_batches = self._place_batches(batches)
-            params_p, lora_p = self._place_model(spec, fresh=stage_entry)
-            round_fn, aux = self._round_fn(spec)
-            if self._hetero:
-                new_lora, _metrics = round_fn(
-                    params_p, lora_p, dev_batches, jnp.float32(lr),
-                    jnp.asarray(plan.step_mask),
-                    jnp.asarray(plan.weights))
-            else:
-                new_lora, _metrics = round_fn(params_p, lora_p,
-                                              dev_batches,
-                                              jnp.float32(lr))
-            up_bytes = aux["up"]
-            new_lora = strat.post_round(state, new_lora)
+                # ---- local training + aggregation (one device program)
+                lr = strat.client_lr(stage)
+                with TraceAnnotation("repro.round.place",
+                                     bytes=_tree_bytes(batches)):
+                    dev_batches = self._place_batches(batches)
+                    params_p, lora_p = self._place_model(
+                        spec, fresh=stage_entry)
+                with TraceAnnotation("repro.round.dispatch"):
+                    round_fn, aux = self._round_fn(spec)
+                    if self._hetero:
+                        new_lora, _metrics = round_fn(
+                            params_p, lora_p, dev_batches, jnp.float32(lr),
+                            jnp.asarray(plan.step_mask),
+                            jnp.asarray(plan.weights))
+                    else:
+                        new_lora, _metrics = round_fn(params_p, lora_p,
+                                                      dev_batches,
+                                                      jnp.float32(lr))
+                    up_bytes = aux["up"]
+                    new_lora = strat.post_round(state, new_lora)
 
-            # ---- eval (every eval_every rounds; last round always) ----
-            if rnd % fed.eval_every == 0 or rnd == n_rounds - 1:
-                ev_loss, ev_acc = self._eval_fn(spec.cfg)(
-                    params_p, new_lora, eval_batch)
+                # ---- eval (every eval_every rounds; last round always)
+                if rnd % fed.eval_every == 0 or rnd == n_rounds - 1:
+                    with TraceAnnotation("repro.eval.dispatch"):
+                        ev_loss, ev_acc = self._eval_fn(spec.cfg)(
+                            params_p, new_lora, eval_batch)
 
-            # ---- overlap: prefetch round r+1 while round r computes ---
-            if rnd + 1 < n_rounds:
-                clients, batches = self._host_batches(rnd + 1)
+                # ---- overlap: prefetch round r+1 while round r computes
+                if rnd + 1 < n_rounds:
+                    with TraceAnnotation("repro.round.host_batches") as sp:
+                        clients, batches = self._host_batches(rnd + 1)
+                        sp.set_metadata(bytes=_tree_bytes(batches))
 
-            # ---- accounting (previous round's scalars fetched only
-            #      after this round's work has been dispatched) ----------
-            if pending is not None:
-                logs.append(self._fetch(pending))
-                if progress:
-                    progress(logs[-1])
-            n_kept = int(plan.kept.sum())
-            pending = RoundLog(
-                round=rnd, stage=stage, capacity=capn,
-                eval_loss=ev_loss, eval_acc=ev_acc,
-                # dropped stragglers never upload; every sampled client
-                # still downloaded the round's adapters
-                comm_bytes_up=strat.uplink_bytes(up_bytes, n_kept),
-                comm_bytes_down=strat.downlink_bytes(new_lora, n_sample),
-                flops=_round_flops(spec.params, plan.total_steps,
-                                   fed.local_batch, fed.seq),
-                memory_bytes=_memory_bytes(spec.params, new_lora,
-                                           fed.local_batch, fed.seq,
-                                           spec.cfg),
-                sim_time_s=sim_time,
-                n_dropped=plan.n_dropped,
-            )
+                # ---- accounting (previous round's scalars fetched only
+                #      after this round's work has been dispatched) ------
+                if pending is not None:
+                    logs.append(self._fetch(pending))
+                    if progress:
+                        progress(logs[-1])
+                with TraceAnnotation("repro.round.books"):
+                    n_kept = int(plan.kept.sum())
+                    pending = RoundLog(
+                        round=rnd, stage=stage, capacity=capn,
+                        eval_loss=ev_loss, eval_acc=ev_acc,
+                        # dropped stragglers never upload; every sampled
+                        # client still downloaded the round's adapters
+                        comm_bytes_up=strat.uplink_bytes(up_bytes, n_kept),
+                        comm_bytes_down=strat.downlink_bytes(new_lora,
+                                                             n_sample),
+                        flops=_round_flops(spec.params, plan.total_steps,
+                                           fed.local_batch, fed.seq),
+                        memory_bytes=_memory_bytes(spec.params, new_lora,
+                                                   fed.local_batch, fed.seq,
+                                                   spec.cfg),
+                        sim_time_s=sim_time,
+                        n_dropped=plan.n_dropped,
+                    )
         if pending is not None:
             logs.append(self._fetch(pending))
             if progress:
                 progress(logs[-1])
 
-        self.lora = strat.finalize(state)
+        with TraceAnnotation("repro.run.finalize"):
+            self.lora = strat.finalize(state)
         self._run_state = None
         return logs
 
@@ -507,6 +553,7 @@ class FederatedRunner:
     def _fetch(log: RoundLog) -> RoundLog:
         """Materialise a pending log's device scalars (the only blocking
         reads in the loop)."""
-        log.eval_loss = float(log.eval_loss)
-        log.eval_acc = float(log.eval_acc)
+        with TraceAnnotation("repro.round.fetch"):
+            log.eval_loss = float(log.eval_loss)
+            log.eval_acc = float(log.eval_acc)
         return log

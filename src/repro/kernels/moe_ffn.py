@@ -114,7 +114,7 @@ def moe_expert_ffn_ecd(buf: jax.Array, wg: jax.Array, wu: jax.Array,
         wd = jnp.pad(wd, ((0, 0), (0, f_p - ff), (0, d_p - d)))
 
     out = pl.pallas_call(
-        _moe_ffn_kernel,
+        _moe_ffn_kernel, name="moe_expert_ffn",
         grid=lay.grid,
         in_specs=[
             pl.BlockSpec((1, block_c, d_p), lambda e_, c_, f_: (e_, c_, 0)),

@@ -40,6 +40,7 @@ from typing import Callable, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.models import transformer as T
 from repro.serving.adapters import AdapterRegistry
@@ -100,6 +101,7 @@ class ServingEngine:
         self._step_fn = jax.jit(self._build_step(),
                                 donate_argnums=self.DONATE_ARGNUMS)
         self._warm = False
+        self._n_steps = 0
 
     # ---- jitted step -------------------------------------------------
     def _build_step(self):
@@ -114,8 +116,9 @@ class ServingEngine:
                     lambda x: jnp.moveaxis(x[idx], 0, 1), lora_op)
             else:
                 lora = lora_op
-            logits, new_cache = T.decode_step(cfg, params, lora, tokens,
-                                              cache)
+            with jax.named_scope("decode"):
+                logits, new_cache = T.decode_step(cfg, params, lora,
+                                                  tokens, cache)
             # per-slot active mask: free/finished slots stay frozen (their
             # lanes still compute, but the cursor does not advance)
             new_cache["pos"] = jnp.where(active, new_cache["pos"],
@@ -178,14 +181,18 @@ class ServingEngine:
             else self.lora
 
     def _admit(self) -> None:
-        now = self._clock()
-        for slot, req in self.scheduler.admit():
-            self.kv.reset_slot(slot)
-            if self.adapters is not None:
-                self._adapter_idx[slot] = self.adapters.index(req.adapter)
-                self.adapters.pin(req.adapter)
-            req.t_admit = now
-            req.state = RequestState.PREFILL
+        with TraceAnnotation("repro.engine.admit") as span:
+            now = self._clock()
+            admitted = self.scheduler.admit()
+            for slot, req in admitted:
+                self.kv.reset_slot(slot)
+                if self.adapters is not None:
+                    self._adapter_idx[slot] = self.adapters.index(
+                        req.adapter)
+                    self.adapters.pin(req.adapter)
+                req.t_admit = now
+                req.state = RequestState.PREFILL
+            span.set_metadata(admitted=len(admitted))
 
     def _finish(self, slot: int, req: Request, now: float) -> None:
         req.state = RequestState.FINISHED
@@ -197,47 +204,62 @@ class ServingEngine:
 
     def step(self) -> List[Request]:
         """Admit what fits, run one batched decode step, harvest slot
-        outputs. Returns the requests that finished this step."""
-        self._admit()
-        active = self.scheduler.active
-        if not active:
-            return []
-        n = self.scheduler.n_slots
-        tokens = np.zeros((n, 1), np.int32)
-        mask = np.zeros((n,), bool)
-        for slot, req in active:
-            tokens[slot, 0] = req.next_feed()
-            mask[slot] = True
+        outputs. Returns the requests that finished this step.
 
-        t0 = self._clock()
-        nxt, cache = self._step_fn(
-            self.params, self._lora_operand(),
-            jnp.asarray(self._adapter_idx), jnp.asarray(tokens),
-            self.kv.cache, jnp.asarray(mask))
-        nxt_host = np.asarray(nxt)                 # blocks on the device
-        dt = self._clock() - t0
-        now = t0 + dt
-        self.kv.cache = cache
+        Host spans: ``repro.engine.step`` (a ``StepTraceAnnotation``)
+        holds ``repro.engine.admit`` (arg ``admitted``),
+        ``repro.engine.assemble`` (the token and mask arrays),
+        ``repro.engine.decode`` (the dispatch and the blocking read of
+        the next tokens; arg ``active``) and ``repro.engine.harvest``
+        (arg ``finished``)."""
+        with StepTraceAnnotation("repro.engine.step",
+                                 step_num=self._n_steps):
+            self._n_steps += 1
+            self._admit()
+            active = self.scheduler.active
+            if not active:
+                return []
+            with TraceAnnotation("repro.engine.assemble"):
+                n = self.scheduler.n_slots
+                tokens = np.zeros((n, 1), np.int32)
+                mask = np.zeros((n,), bool)
+                for slot, req in active:
+                    tokens[slot, 0] = req.next_feed()
+                    mask[slot] = True
 
-        done = []
-        for slot, req in active:
-            if req.cursor < req.prompt_len:        # consumed a prompt token
-                req.cursor += 1
-                req.prefill_s += dt
-                if req.cursor < req.prompt_len:
-                    continue                        # still prefilling
-                # last prompt token -> this step produced the first output
-                req.t_first_token = now
-                req.state = RequestState.DECODE
-            else:
-                req.decode_times.append(dt)
-            tok = int(nxt_host[slot])
-            req.generated.append(tok)
-            if (len(req.generated) >= req.max_new_tokens
-                    or tok in req.stop_tokens):
-                self._finish(slot, req, now)
-                done.append(req)
-        return done
+            with TraceAnnotation("repro.engine.decode", active=len(active)):
+                t0 = self._clock()
+                nxt, cache = self._step_fn(
+                    self.params, self._lora_operand(),
+                    jnp.asarray(self._adapter_idx), jnp.asarray(tokens),
+                    self.kv.cache, jnp.asarray(mask))
+                nxt_host = np.asarray(nxt)         # blocks on the device
+                dt = self._clock() - t0
+            now = t0 + dt
+            self.kv.cache = cache
+
+            with TraceAnnotation("repro.engine.harvest") as span:
+                done = []
+                for slot, req in active:
+                    if req.cursor < req.prompt_len:  # consumed a prompt token
+                        req.cursor += 1
+                        req.prefill_s += dt
+                        if req.cursor < req.prompt_len:
+                            continue                  # still prefilling
+                        # last prompt token -> this step produced the
+                        # first output
+                        req.t_first_token = now
+                        req.state = RequestState.DECODE
+                    else:
+                        req.decode_times.append(dt)
+                    tok = int(nxt_host[slot])
+                    req.generated.append(tok)
+                    if (len(req.generated) >= req.max_new_tokens
+                            or tok in req.stop_tokens):
+                        self._finish(slot, req, now)
+                        done.append(req)
+                span.set_metadata(finished=len(done))
+            return done
 
     def has_work(self) -> bool:
         return self.scheduler.has_work()

@@ -5,7 +5,9 @@ Each test lowers one kernel through its registered ``pallas`` entry with
 (no chip needed: the TPU compiler is installed with jax) and asserts the
 compiled program holds a ``tpu_custom_call`` — the kernel itself, not a
 fallback. Interpret-mode tests cannot see what the chip's compiler
-refuses: block shapes off the (8, 128) tiling, VMEM overflow.
+refuses: block shapes off the (8, 128) tiling, VMEM overflow. The last
+test pins the instruction name each kernel has in the programs that run
+it, which is the name a device trace shows.
 
 Widths: qwen2-7b (d_model 3584, 28 query / 4 KV heads of 128, d_ff
 18944) for the attention, LoRA and decode kernels; mamba2-2.7b (80 heads
@@ -16,7 +18,9 @@ The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and the test
 workers all import this file.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +28,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import dispatch
+from repro.models import transformer as T
 
 BF16 = jnp.bfloat16
 
@@ -103,3 +108,58 @@ def test_moe_expert_ffn_compiles(one_chip):
                          [((e, c, d), BF16), ((e, d, ff), BF16),
                           ((e, d, ff), BF16), ((e, ff, d), BF16)])
     assert "tpu_custom_call" in text
+
+
+def _kernel_names(text):
+    """The instruction names of the compiled program's Pallas kernels,
+    without their ``.N`` suffix: the names the device trace shows."""
+    return {re.match(r"(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ", line.strip())[1]
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line}
+
+
+def test_kernel_names_in_the_round_and_decode_programs(one_chip,
+                                                       monkeypatch):
+    """Each kernel keeps its own name inside the programs that run it
+    (a local training step of an MoE model, vmapped over two clients,
+    and the engine's decode step); an unnamed kernel would show as the
+    call around it (``closed_call``)."""
+    from repro.analysis.contracts.serving import _step_fn
+    from repro.configs import get_config
+    from repro.federated.client import make_local_train
+
+    # compile the kernels for the chip, not for the interpreter
+    monkeypatch.setattr(dispatch, "interpret_default",
+                        lambda platform=None: False)
+    cfg = get_config("granite-moe-1b-a400m")
+    cfg = dataclasses.replace(
+        cfg, n_layers=2, d_model=256, n_heads=4, n_kv_heads=2,
+        head_dim=64, d_ff=256, vocab=1024, kernel_backend="pallas",
+        moe=dataclasses.replace(cfg.moe, n_experts=4, top_k=2,
+                                d_ff_expert=256))
+
+    def shapes(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    key = jax.random.PRNGKey(0)
+    params = shapes(jax.eval_shape(lambda: T.init_params(cfg, key, BF16)))
+    lora = shapes(jax.eval_shape(lambda: T.init_lora(cfg, key, rank=8)))
+    tok = jax.ShapeDtypeStruct((2, 2, 2, 128), jnp.int32, sharding=one_chip)
+    local = make_local_train(cfg)
+    train = jax.jit(lambda p, l, b: jax.vmap(
+        lambda bt: local(p, l, bt, 1e-4))(b))
+    text = train.lower(params, lora, {"tokens": tok, "labels": tok}) \
+        .compile().as_text()
+    assert _kernel_names(text) == {"flash_attention", "lora_matmul",
+                                   "moe_expert_ffn"}
+
+    n = 4
+    cache = shapes(jax.eval_shape(lambda: T.init_cache(cfg, n, 256, BF16)))
+    i32 = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    text = jax.jit(_step_fn(cfg, multi=False)).lower(
+        params, lora, i32,
+        jax.ShapeDtypeStruct((n, 1), jnp.int32, sharding=one_chip), cache,
+        jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip)) \
+        .compile().as_text()
+    assert _kernel_names(text) == {"flash_decode", "moe_expert_ffn"}
