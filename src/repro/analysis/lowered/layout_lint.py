@@ -38,29 +38,15 @@ import numpy as np
 
 from repro.kernels.common import (
     LANE,
+    VMEM_BUDGET_BYTES,
     BlockLayout,
     OperandLayout,
-    round_up,
     sublane,
 )
 
-#: VMEM bytes available to one kernel instance, per platform. TPU v5e
-#: cores carry 16 MiB less compiler-reserved headroom; unknown
+#: VMEM bytes available to one kernel instance, per platform; unknown
 #: platforms get the TPU budget (the kernels are TPU-targeted).
-VMEM_BUDGET = {"tpu": 14 * 1024 * 1024}
-_DEFAULT_BUDGET = 14 * 1024 * 1024
-
-
-def _tile_bytes(shape, dtype) -> int:
-    """Bytes a block actually occupies in VMEM: last two dims rounded
-    up to the dtype tile, leading dims multiplied through."""
-    dt = np.dtype(dtype)
-    dims = list(shape)
-    if len(dims) >= 1:
-        dims[-1] = round_up(dims[-1], LANE)
-    if len(dims) >= 2:
-        dims[-2] = round_up(dims[-2], sublane(dt))
-    return int(np.prod(dims, dtype=np.int64)) * dt.itemsize
+VMEM_BUDGET = {"tpu": VMEM_BUDGET_BYTES}
 
 
 def _check_operand(name: str, op: OperandLayout) -> List[str]:
@@ -110,10 +96,8 @@ def lint_layout(layout: BlockLayout, platform: str = "tpu") -> List[str]:
         msgs.append(f"accumulator dtype {layout.accum_dtype} is below "
                     f"fp32 — MXU accumulation must be float32 or wider")
 
-    vmem = sum(2 * _tile_bytes(op.block, op.dtype)   # double-buffered
-               for op in named.values() if op.memory == "vmem")
-    vmem += sum(_tile_bytes(sc.shape, sc.dtype) for sc in layout.scratch)
-    budget = VMEM_BUDGET.get(platform, _DEFAULT_BUDGET)
+    vmem = layout.vmem_bytes()
+    budget = VMEM_BUDGET.get(platform, VMEM_BUDGET_BYTES)
     if vmem > budget:
         msgs.append(f"estimated VMEM footprint {vmem} bytes "
                     f"(double-buffered blocks + scratch) exceeds the "

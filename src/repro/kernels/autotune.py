@@ -2,13 +2,14 @@
 
 The kernels expose their schedule knobs (``block_q``/``block_k``/
 ``block_m``/``block_n``/``block_c``/``block_f``) as static kwargs with
-conservative defaults. This module sweeps those knobs per (kernel,
-shape) pair, times real compiled calls with warm-up excluded, and
-persists the winners to a **platform-keyed** JSON tuning cache that
-``dispatch.get_kernel`` consults at kernel resolution — so a tuned TPU
-run picks up its block sizes with no call-site changes, while CPU /
-interpret behavior is untouched (cache misses fall back to the
-defaults).
+conservative defaults (``lora_matmul``'s are derived from the call's
+shape, and its sweep spans the sizes that rule picks). This module
+sweeps those knobs per (kernel, shape) pair, times real compiled calls
+with warm-up excluded, and persists the winners to a
+**platform-keyed** JSON tuning cache that ``dispatch.get_kernel``
+consults at kernel resolution — so a tuned TPU run picks up its block
+sizes with no call-site changes, while CPU / interpret behavior is
+untouched (cache misses fall back to the defaults).
 
 Design rules (DESIGN.md §14):
 
@@ -62,18 +63,20 @@ DEFAULT_CACHE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 TUNABLES: Dict[str, Dict[str, Tuple[int, ...]]] = {
     "flash_attention": {"block_q": (64, 128, 256),
                         "block_k": (64, 128, 256)},
-    "lora_matmul": {"block_m": (64, 128, 256), "block_n": (128, 256),
-                    "block_k": (128, 256)},
+    "lora_matmul": {"block_m": (64, 128, 256, 512, 1024),
+                    "block_n": (128, 256, 512, 896),
+                    "block_k": (128, 256, 512, 896)},
     "flash_decode": {"block_k": (64, 128, 256, 512)},
     "moe_expert_ffn": {"block_c": (64, 128, 256),
                        "block_f": (128, 256, 512)},
 }
 
 #: the kernels' built-in defaults (must mirror the wrapper signatures
-#: in ``repro.kernels.ops``; pinned by tests/test_autotune.py)
-DEFAULTS: Dict[str, Dict[str, int]] = {
+#: in ``repro.kernels.ops``; pinned by tests/test_autotune.py). ``None``
+#: is "derived from the call's shape" (``lora_matmul``'s blocks).
+DEFAULTS: Dict[str, Dict[str, Optional[int]]] = {
     "flash_attention": {"block_q": 128, "block_k": 128},
-    "lora_matmul": {"block_m": 128, "block_n": 128, "block_k": 128},
+    "lora_matmul": {"block_m": None, "block_n": None, "block_k": None},
     "flash_decode": {"block_k": 128},
     "moe_expert_ffn": {"block_c": 128, "block_f": 256},
 }

@@ -53,6 +53,34 @@ def tile_block_cap(default: int, dim: int, granule: int) -> int:
     return min(default, round_up(dim, granule))
 
 
+def fewest_blocks(dim: int, cap: int, granule: int) -> int:
+    """The block that covers ``dim`` in the fewest blocks of at most
+    ``cap`` (a ``granule`` multiple), each the smallest ``granule``
+    multiple that does: 3584 at cap 512 is 7 blocks of 512, 640 is 2 of
+    384 (padding 128, where 512 would pad 384)."""
+    d = round_up(dim, granule)
+    n_blocks = -(-d // cap)
+    return round_up(-(-d // n_blocks), granule)
+
+
+#: VMEM bytes one kernel instance may declare (double-buffered blocks
+#: plus scratch): v5e's 16 MiB default scoped VMEM less headroom for
+#: what Mosaic keeps itself. The L003 lint holds every layout to it.
+VMEM_BUDGET_BYTES = 14 * 1024 * 1024
+
+
+def tile_bytes(shape, dtype) -> int:
+    """Bytes a block actually occupies in VMEM: last two dims rounded
+    up to the dtype tile, leading dims multiplied through."""
+    dt = np.dtype(dtype)
+    dims = list(shape)
+    if len(dims) >= 1:
+        dims[-1] = round_up(dims[-1], LANE)
+    if len(dims) >= 2:
+        dims[-2] = round_up(dims[-2], sublane(dt))
+    return int(np.prod(dims, dtype=np.int64)) * dt.itemsize
+
+
 @dataclasses.dataclass(frozen=True)
 class OperandLayout:
     """One pallas_call operand as the layout lint sees it: the PADDED
@@ -78,3 +106,12 @@ class BlockLayout:
     outputs: Dict[str, OperandLayout]
     scratch: Tuple[OperandLayout, ...] = ()
     accum_dtype: str = "float32"
+
+    def vmem_bytes(self) -> int:
+        """Estimated VMEM footprint: every VMEM operand and output block
+        double-buffered, plus the scratch."""
+        blocks = [*self.operands.values(), *self.outputs.values()]
+        return (sum(2 * tile_bytes(op.block, op.dtype)
+                    for op in blocks if op.memory == "vmem")
+                + sum(tile_bytes(sc.shape, sc.dtype)
+                      for sc in self.scratch))

@@ -8,15 +8,26 @@ main matmul's k-loop means x is read from HBM **once** — the adapter adds
 2·r·(m+n) FLOPs per tile but zero extra activation traffic, instead of a
 second kernel launch + extra read of x in the naive two-pass form.
 
-Grid: (nm, nn, nk), k innermost; the (bm × r) x@A partial accumulates in
-VMEM scratch alongside the main (bm × bn) accumulator; the B-side rank
-contraction happens once on the final k step.
+Grid: (nm, nn, nk), k innermost. The main (bm × bn) product accumulates
+in a float32 VMEM scratch over k. The (bm × r) x@A partial accumulates
+in a second scratch over k on the first column block only, and every
+later column block of the same rows reuses it; the B-side rank
+contraction happens on each block's final k step.
+
+Blocks are derived from the call's shape unless the caller or the tuning
+cache names them (``lora_layout``): the fewest sublane-aligned row blocks
+of at most 1024 and lane-aligned column and contraction blocks of at most
+1024 and 512, within the VMEM budget. At qwen2-7b's q projection (1024 ×
+3584 → 3584) that is 28 grid steps of (1024, 896, 512), where 128³ tiles
+took 6272.
 
 ``scaling`` (alpha/r — see ``repro.models.layers.lora_scaling``) is a
 **traced operand** carried as a (1, 1) SMEM scalar, not a compile-time
 constant: runs with different alpha values share one compiled kernel.
 """
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -25,29 +36,29 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (
     LANE,
+    VMEM_BUDGET_BYTES,
     BlockLayout,
     OperandLayout,
+    fewest_blocks,
     round_up,
     sublane,
     tile_block_cap,
 )
 
 
-def lora_layout(m: int, k: int, n: int, r: int, dtype=jnp.float32, *,
-                block_m: int = 128, block_n: int = 128,
-                block_k: int = 128) -> BlockLayout:
-    """Declared block layout of ``lora_matmul`` at one shape (the
-    wrapper derives grid/padding/blocks from this; L003 lints it).
+#: Caps of the derived blocks (rows, output columns, contraction). A
+#: (1024, 896, 512) bf16 step does 940 MFLOP over 1.9 MiB of x and w
+#: tiles, 478 FLOP/byte, above v5e's ridge of 197 TFLOP/s / 819 GB/s =
+#: 240; a 128^3 step reaches 57 FLOP/byte, and its fixed per-step cost
+#: outweighs its 21 ns of MXU work. Of the tilings that fit the default scoped VMEM,
+#: this one timed fastest on a TPU v5e at 1024 x 3584 -> 3584 (161.7 us,
+#: against 2282 us at 128^3).
+M_CAP = 1024
+N_CAP = 1024
+K_CAP = 512
 
-    ``block_m`` is only ever a sublane (x and out rows) so it caps to
-    the sublane granule; ``block_k``/``block_n`` each appear as a lane
-    dim (x cols / w+b+out cols) so they cap to LANE multiples — the
-    old ``min(block, dim)`` cap produced e.g. a 64-wide lane block for
-    k=64, which Mosaic can only lower via padded strided tiles."""
-    g = sublane(dtype)
-    block_m = tile_block_cap(block_m, m, g)
-    block_n = tile_block_cap(block_n, n, LANE)
-    block_k = tile_block_cap(block_k, k, LANE)
+
+def _layout(m, k, n, r, dtype, block_m, block_n, block_k) -> BlockLayout:
     mp = round_up(m, block_m)
     kp = round_up(k, block_k)
     np_ = round_up(n, block_n)
@@ -69,20 +80,62 @@ def lora_layout(m: int, k: int, n: int, r: int, dtype=jnp.float32, *,
                  OperandLayout((block_m, r), (block_m, r), "float32")))
 
 
+def lora_layout(m: int, k: int, n: int, r: int, dtype=jnp.float32, *,
+                block_m: Optional[int] = None,
+                block_n: Optional[int] = None,
+                block_k: Optional[int] = None) -> BlockLayout:
+    """Declared block layout of ``lora_matmul`` at one shape (the
+    wrapper derives grid/padding/blocks from this; L003 lints it).
+
+    A block the caller names is capped to its dim: ``block_m`` is only
+    ever a sublane (x and out rows) so it caps to the sublane granule;
+    ``block_k``/``block_n`` each appear as a lane dim (x cols / w+b+out
+    cols) so they cap to LANE multiples. A block left ``None`` is
+    derived from the shape: the fewest blocks of at most ``M_CAP``,
+    ``N_CAP`` or ``K_CAP`` that cover the dim, with the row block halved
+    until the footprint fits ``VMEM_BUDGET_BYTES``."""
+    g = sublane(dtype)
+    block_n = (tile_block_cap(block_n, n, LANE) if block_n
+               else fewest_blocks(n, N_CAP, LANE))
+    block_k = (tile_block_cap(block_k, k, LANE) if block_k
+               else fewest_blocks(k, K_CAP, LANE))
+    if block_m:
+        return _layout(m, k, n, r, dtype, tile_block_cap(block_m, m, g),
+                       block_n, block_k)
+    cap = M_CAP
+    while True:
+        lay = _layout(m, k, n, r, dtype, fewest_blocks(m, cap, g),
+                      block_n, block_k)
+        if cap <= g or lay.vmem_bytes() <= VMEM_BUDGET_BYTES:
+            return lay
+        cap = round_up(cap // 2, g)
+
+
 def _lora_kernel(x_ref, w_ref, a_ref, b_ref, s_ref, o_ref, acc_ref, xa_ref):
+    # the grid runs (i, j, k) in order, j and k sequential (the default
+    # dimension semantics): x@A depends on the row block i alone, so it
+    # accumulates over k on the first column block and every later j
+    # reuses it
+    j = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
 
     @pl.when(ki == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when((j == 0) & (ki == 0))
+    def _init_xa():
         xa_ref[...] = jnp.zeros_like(xa_ref)
 
     x = x_ref[...]
     acc_ref[...] += jax.lax.dot(x, w_ref[...],
                                 preferred_element_type=jnp.float32)
-    xa_ref[...] += jax.lax.dot(x, a_ref[...],
-                               preferred_element_type=jnp.float32)
+
+    @pl.when(j == 0)
+    def _xa():
+        xa_ref[...] += jax.lax.dot(x, a_ref[...],
+                                   preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -92,12 +145,14 @@ def _lora_kernel(x_ref, w_ref, a_ref, b_ref, s_ref, o_ref, acc_ref, xa_ref):
 
 
 def lora_matmul(x: jax.Array, w: jax.Array, a: jax.Array, b: jax.Array, *,
-                scaling=1.0, block_m: int = 128,
-                block_n: int = 128, block_k: int = 128,
+                scaling=1.0, block_m: Optional[int] = None,
+                block_n: Optional[int] = None,
+                block_k: Optional[int] = None,
                 interpret: bool = False) -> jax.Array:
     """x: (M, K); w: (K, N); a: (K, r); b: (r, N) -> (M, N).
 
     ``scaling`` may be a Python float or a traced scalar (alpha/r).
+    Blocks left ``None`` are derived from the shape (``lora_layout``).
     """
     m, k = x.shape
     _, n = w.shape

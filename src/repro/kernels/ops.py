@@ -217,13 +217,14 @@ _lora.defvjp(_lora_fwd, _lora_bwd)
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n",
                                              "block_k", "interpret"))
-def lora_matmul(x, w, a, b, *, scaling=1.0, block_m: int = 128,
-                block_n: int = 128, block_k: int = 128,
-                interpret: bool = False):
+def lora_matmul(x, w, a, b, *, scaling=1.0, block_m: Optional[int] = None,
+                block_n: Optional[int] = None,
+                block_k: Optional[int] = None, interpret: bool = False):
     """x: (..., K) any leading dims; w (K,N); a (K,r); b (r,N).
 
     ``scaling`` = alpha/r (``lora_scaling``). It is a traced operand —
-    runs differing only in alpha share one compiled kernel.
+    runs differing only in alpha share one compiled kernel. Blocks left
+    ``None`` are derived from the shape (``lora_layout``).
     """
     scaling = jnp.asarray(scaling, jnp.float32)
     return _lora(x, w, a, b, scaling, block_m, block_n, block_k, interpret)
@@ -249,9 +250,9 @@ def lora_matmul_layout(x, w, a, b, **kwargs):
     """BlockLayout of ``lora_matmul`` for model-layout avals."""
     m = math.prod(x.shape[:-1])
     return lora_layout(m, x.shape[-1], w.shape[1], a.shape[1], x.dtype,
-                       block_m=kwargs.get("block_m", 128),
-                       block_n=kwargs.get("block_n", 128),
-                       block_k=kwargs.get("block_k", 128))
+                       block_m=kwargs.get("block_m"),
+                       block_n=kwargs.get("block_n"),
+                       block_k=kwargs.get("block_k"))
 
 
 def ssd_scan_layout(x, dt, a, b, c, d, **kwargs):

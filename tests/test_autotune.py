@@ -3,6 +3,7 @@ dispatch consultation (hit, miss, explicit-kwarg precedence), candidate
 enumeration through the declared layouts, and determinism of the
 selected config under an injected measurement."""
 import json
+import math
 
 import jax
 import jax.numpy as jnp
@@ -177,7 +178,8 @@ def test_candidates_are_default_first_lint_valid_and_deduped():
     cands = autotune.candidate_configs("lora_matmul", layout_fn, args, {})
     assert cands[0] == DEFAULTS["lora_matmul"]
     # tiny dims cap every block -> heavy dedup, but never zero
-    assert 1 <= len(cands) <= 3 * 2 * 2 + 1
+    sweep = math.prod(len(v) for v in TUNABLES["lora_matmul"].values())
+    assert 1 <= len(cands) <= sweep + 1
     from repro.analysis.lowered.layout_lint import lint_layout
     seen = set()
     for cfg in cands:

@@ -1,11 +1,16 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles
 (interpret=True executes the kernel bodies in Python on CPU)."""
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis.lowered.layout_lint import lint_layout
 from repro.kernels import ops, ref
+from repro.kernels.common import VMEM_BUDGET_BYTES
+from repro.kernels.lora_matmul import lora_layout
 
 
 def _assert_close(got, want, dtype):
@@ -144,6 +149,10 @@ def test_ssd_chunked_model_path_matches_ref():
     (64, 64, 64, 8, 32),
     (100, 96, 72, 4, 32),    # ragged everything -> padding path
     (128, 256, 128, 32, 64),
+    # blk None: tiles derived from the shape
+    (96, 448, 1152, 8, None),    # 2 column blocks of 640 reuse one x@A
+    (8, 256, 384, 16, None),     # decode-like: m under one row block
+    (1100, 200, 1152, 8, None),  # 2 row blocks of 552 x 2 column blocks
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_lora_matmul(m, k, n, r, blk, dtype):
@@ -155,10 +164,38 @@ def test_lora_matmul(m, k, n, r, blk, dtype):
          ).astype(dtype)
     b = (jax.random.normal(jax.random.fold_in(key, 3), (r, n)) * 0.1
          ).astype(dtype)
-    got = ops.lora_matmul(x, w, a, b, block_m=blk, block_n=blk, block_k=blk,
-                          interpret=True)
+    blocks = {} if blk is None else dict(block_m=blk, block_n=blk,
+                                         block_k=blk)
+    got = ops.lora_matmul(x, w, a, b, **blocks, interpret=True)
     want = ref.lora_matmul_ref(x, w, a, b)
     _assert_close(got, want, dtype)
+
+
+@pytest.mark.parametrize("m,n,max_steps", [
+    (1024, 3584, 28),     # qwen2-7b q projection, one client's 2 x 512
+    (1024, 512, 7),       # its v projection
+    (8192, 3584, 224),    # the eval's q projection, 16 x 512 rows
+    (64, 3584, 28),       # shared-adapter decode over 64 slots
+])
+def test_lora_layout_derives_tiles_from_the_shape(m, n, max_steps):
+    """With no blocks named, the layout at qwen2-7b's widths (rank-32
+    bf16 LoRA on d_model 3584) takes few grid steps, each above v5e's
+    ridge of 240 FLOP/byte where the rows allow, and fits the declared
+    VMEM budget; named blocks still win. No kernel runs."""
+    lay = lora_layout(m, 3584, n, 32, jnp.bfloat16)
+    assert math.prod(lay.grid) <= max_steps
+    assert lay.vmem_bytes() <= VMEM_BUDGET_BYTES
+    assert lint_layout(lay) == []
+    bm, bk = lay.operands["x"].block
+    bn = lay.operands["w"].block[1]
+    if m == 64:
+        assert bm == 64
+    else:
+        flops, bytes_ = 2 * bm * bk * bn, 2 * (bm * bk + bk * bn)
+        assert flops / bytes_ >= 240
+    named = lora_layout(m, 3584, n, 32, jnp.bfloat16, block_m=128,
+                        block_n=128, block_k=128)
+    assert named.grid == (-(-m // 128), n // 128, 28)
 
 
 def test_lora_matmul_batched_leading_dims():

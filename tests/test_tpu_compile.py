@@ -81,6 +81,17 @@ def test_lora_matmul_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("m,n", [(1024, 3584), (8192, 512)])
+def test_lora_matmul_compiles_with_derived_tiles(one_chip, m, n):
+    """The tiles ``lora_layout`` derives at the round cell's shapes (one
+    client's q projection, the eval's v projection) fit the chip."""
+    text = _compile_text(one_chip, "lora_matmul",
+                         [((m, 3584), BF16), ((3584, n), BF16),
+                          ((3584, 32), BF16), ((32, n), BF16)],
+                         scaling=2.0)
+    assert "tpu_custom_call" in text
+
+
 def test_flash_decode_compiles(one_chip):
     kernel = dispatch.get_kernel("flash_decode", "pallas")
     q = jax.ShapeDtypeStruct((8, 1, 28, 128), BF16, sharding=one_chip)
