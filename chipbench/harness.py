@@ -5,6 +5,7 @@ per-layer metric readers, and the result line.
 from __future__ import annotations
 
 import contextlib
+import importlib
 import importlib.util
 import json
 import math
@@ -193,17 +194,51 @@ def cell_metrics(bench: dict, cell: str) -> Dict[str, list]:
     return {"end_to_end": e2e, "per_layer": layer}
 
 
+def cell_module(root: str, kind: str):
+    """``chipbench/<kind>_cell.py``, the module that runs cells of that
+    ``kind`` (``run(cell, devices, meter)``, ``readings(cell, devices,
+    control, meter)``)."""
+    path = os.path.join(root, "chipbench", kind + "_cell.py")
+    if not os.path.exists(path):
+        raise BenchError(f"no module {path} for cells of kind {kind!r}")
+    if os.path.abspath(os.path.dirname(path)) == HERE:
+        return importlib.import_module("chipbench." + kind + "_cell")
+    # another checkout's file: loaded under a name of its own
+    return _load(path, "chipbench_cell_" + kind)
+
+
+def _load(path: str, name: str):
+    """The module in the file ``path``, run under ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod          # where dataclasses look it up
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def cell_devices(cell, rehearse: bool):
+    """The devices a cell runs on: as many TPU chips as it asks for, or,
+    rehearsing, as many of whatever devices JAX has."""
+    import jax
+
+    chips = cell.workload["chips"]
+    if not rehearse:
+        return require_tpu(chips)
+    devices = jax.devices()[:chips]
+    if len(devices) < chips:
+        raise BenchError(f"the cell needs {chips} devices; JAX found "
+                         f"{len(devices)}")
+    return devices
+
+
 def read_metric(root: str, name: str, ctx: dict):
     """Run ``chipbench/metrics/<name>.py``'s ``read(ctx)``; None where
     it found nothing to read."""
     path = os.path.join(root, "chipbench", "metrics", name + ".py")
     if not os.path.exists(path):
         raise BenchError(f"no reader {path} for metric {name!r}")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return _load(path, "chipbench_metric_" + name.replace(".", "_")
+                 .replace("-", "_")).read(ctx)
 
 
 def judge(checks: Dict[str, tuple]) -> bool:

@@ -1,8 +1,10 @@
-"""Round cells: whole DevFT schedules of ``FederatedRunner.run`` on one
-runner.
+"""Round cells: whole DevFT or FedIT schedules of ``FederatedRunner.run``
+on one runner.
 
 Set-up makes the base, the starting adapter and the clients' data from
-the seed, builds the runner, and drives it through one whole schedule:
+the seed, builds the runner (on the workload's ``mesh`` where it names
+one, with the base and the adapter made in the shardings the runner
+places them with), and drives it through one whole schedule:
 that compiles every stage's round and eval programs and records, for
 every round, what the round and eval programs were given and what they
 returned (the same runner then serves the window). The window runs
@@ -11,12 +13,21 @@ schedules back to back and ends with the first one that finishes after
 that the workload's ``check_rounds`` lists (in the cells, the first
 round of every stage), each from the program's state before it, with
 the stage transfer into it, and the two are compared.
+
+What is particular to a model's block comes in as ``Parts``: the
+program's config for the file's sizes, the benchmark's weight maker and
+layout, the plain reference, and the operation counts. A kind of cell
+for another block is a file ``chipbench/<kind>_cell.py`` whose ``run``
+and ``readings`` call this module's with its own ``Parts``.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import sys
 import time
+from types import ModuleType
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -88,13 +99,17 @@ def make_data(vocab: int, n_clients: int, seed: int):
 class Tap:
     """Records the first ``n_rounds`` calls of the round and eval
     programs, their inputs and outputs, while ``on``; the calls
-    themselves are the runner's own."""
+    themselves are the runner's own. On a mesh the round program donates
+    its adapter, which the next round then takes in: the record keeps a
+    copy of each round's output."""
 
     def __init__(self, runner, n_rounds: int):
         self.rounds, self.evals, self.n = [], [], n_rounds
         self.on = True
         self._runner = runner
         round_fn, eval_fn = runner._round_fn, runner._eval_fn
+        keep = (lambda t: jax.tree.map(jnp.copy, t)) \
+            if runner.mesh is not None else (lambda t: t)
 
         def tapped_round(spec):
             fn, aux = round_fn(spec)
@@ -102,7 +117,7 @@ class Tap:
             def call(params, lora, batches, lr, *rest):
                 new, metrics = fn(params, lora, batches, lr, *rest)
                 if self.on and len(self.rounds) < self.n:
-                    self.rounds.append({"lora_in": lora, "lora_out": new,
+                    self.rounds.append({"lora_out": keep(new),
                                         "batches": batches, "lr": lr,
                                         "metrics": metrics})
                 return new, metrics
@@ -133,35 +148,77 @@ class Tap:
 # ---------------------------------------------------------------------------
 
 
+@dataclasses.dataclass(frozen=True)
+class Parts:
+    """What a round cell takes from its model's block: the program's
+    config for the file's sizes (``program_cfg(m)``), the benchmark's
+    weights (``base_shapes``, ``base_params``, ``init_lora``), the plain
+    reference (``adamw_steps``, ``eval_loss``, ``quantize`` and DevFT's
+    ``capacities``, ``stage_lr``, ``layer_gram``, ``spectral_groups``,
+    ``fuse``, ``broadcast``) and the operation counts (``train_flops``,
+    ``forward_flops``)."""
+    program_cfg: Callable = program_cfg
+    weights: ModuleType = weights
+    reference: ModuleType = R
+    flops: ModuleType = flops
+
+
+#: this module's own block: dense or MoE GQA decoders
+PARTS = Parts()
+
+#: ``FedConfig`` fields a workload file may set
+FED_KEYS = ("n_clients", "sample_frac", "k_local", "local_batch", "seq",
+            "rounds", "lora_rank", "lr", "method", "n_stages", "growth",
+            "beta", "lr_stage_factor")
+
+
 def fed_config(w: dict, seed: int):
     from repro.federated.simulator import FedConfig
 
-    return FedConfig(
-        n_clients=w["n_clients"], sample_frac=w["sample_frac"],
-        k_local=w["k_local"], local_batch=w["local_batch"], seq=w["seq"],
-        rounds=w["rounds"], lora_rank=w["lora_rank"], lr=w["lr"],
-        method=w["method"], eval_every=1, n_stages=w["n_stages"],
-        growth=w["growth"], beta=w["beta"],
-        lr_stage_factor=w["lr_stage_factor"], seed=seed % (1 << 31))
+    return FedConfig(eval_every=1, seed=seed % (1 << 31),
+                     **{k: w[k] for k in FED_KEYS if k in w})
 
 
-def setup(cell):
+def cell_mesh(cell, devices):
+    """The workload's ``mesh`` (axis name -> size, in order) over its
+    devices, or None for one device."""
+    spec = cell.workload.get("mesh")
+    if spec is None:
+        return None
+    from repro.launch.mesh import make_mesh
+
+    shape = tuple(spec.values())
+    if int(np.prod(shape)) != len(devices):
+        raise harness.BenchError(f"{cell.name}: mesh {spec} does not cover "
+                                 f"its {len(devices)} devices")
+    return make_mesh(shape, tuple(spec), devices=list(devices))
+
+
+def setup(cell, devices, parts: Parts = PARTS):
     """Everything before the window: returns ``(runner, base, lora0,
     tap)`` with one whole schedule driven through ``runner``."""
     from repro.federated import FederatedRunner
     from repro.models import transformer as T
 
     m, w = cell.model, cell.params
-    cfg = program_cfg(m)
+    cfg = parts.program_cfg(m)
     key = jax.random.PRNGKey(0)
-    base = weights.base_params(m, cell.seed)
+    mesh = cell_mesh(cell, devices)
+    base_sh = lora_sh = None
+    if mesh is not None:
+        from repro.launch.sharding import params_shardings
+
+        base_sh = params_shardings(mesh, parts.weights.base_shapes(m))
+        lora_sh = params_shardings(mesh, jax.eval_shape(
+            lambda: parts.weights.init_lora(m, 0, w["lora_rank"])))
+    base = parts.weights.base_params(m, cell.seed, base_sh)
     check_tree(base, cfg, lambda: T.init_params(cfg, key))
-    lora0 = weights.init_lora(m, cell.seed, w["lora_rank"])
+    lora0 = parts.weights.init_lora(m, cell.seed, w["lora_rank"], lora_sh)
     check_tree(lora0, cfg, lambda: T.init_lora(cfg, key, w["lora_rank"]))
     fed = fed_config(w, cell.seed)
     data = make_data(m["vocab_size"], w["n_clients"], cell.seed)
     runner = FederatedRunner(cfg, fed, data, dtype=jnp.dtype(m["dtype"]),
-                             params=base)
+                             params=base, mesh=mesh)
     runner.lora = runner.strategy.init_lora(base, lora0)
     tap = Tap(runner, w["rounds"])
     with harness.span("schedule"):
@@ -171,7 +228,7 @@ def setup(cell):
     return runner, base, lora0, tap
 
 
-def _schedule(m: dict, w: dict):
+def _schedule(m: dict, w: dict, R: ModuleType = R):
     """``(capacities, rounds per stage, stage -> client lr)``: DevFT's
     growing stages, or FedIT's one stage of the whole model."""
     n = m["num_hidden_layers"]
@@ -184,26 +241,27 @@ def _schedule(m: dict, w: dict):
         w["lr"], w["lr_stage_factor"], st, w["n_stages"])
 
 
-def schedule_counts(m: dict, w: dict) -> dict:
+def schedule_counts(m: dict, w: dict, parts: Parts = PARTS) -> dict:
     """Tokens and model operations of one whole schedule."""
+    fl = parts.flops
     n_sample = max(1, int(w["n_clients"] * w["sample_frac"]))
-    caps, per, _ = _schedule(m, w)
+    caps, per, _ = _schedule(m, w, parts.reference)
     rounds = [caps[min(r // per, len(caps) - 1)] for r in range(w["rounds"])]
     tok = n_sample * w["k_local"] * w["local_batch"] * w["seq"]
     ev = w["eval_rows"] * w["seq"]
-    train = sum(tok * flops.train_flops(m, c, w["seq"], w["lora_rank"])
+    train = sum(tok * fl.train_flops(m, c, w["seq"], w["lora_rank"])
                 for c in rounds)
-    evalf = sum(ev * flops.forward_flops(m, c, (w["seq"] + 1) / 2,
-                                         w["lora_rank"]) for c in rounds)
+    evalf = sum(ev * fl.forward_flops(m, c, (w["seq"] + 1) / 2,
+                                      w["lora_rank"]) for c in rounds)
     return {"rounds": len(rounds), "capacities": rounds,
             "train_tokens": tok * len(rounds), "train_flops": train,
             "eval_flops": evalf, "n_sample": n_sample}
 
 
-def run(cell, devices, meter) -> dict:
+def run(cell, devices, meter, parts: Parts = PARTS) -> dict:
     m, w = cell.model, cell.params
-    runner, base, lora0, tap = setup(cell)
-    counts = schedule_counts(m, w)
+    runner, base, lora0, tap = setup(cell, devices, parts)
+    counts = schedule_counts(m, w, parts)
     n_sched = 0
     with cell.window(meter):
         while True:
@@ -216,7 +274,7 @@ def run(cell, devices, meter) -> dict:
     peak = harness.memory_peak_bytes(devices)
     del runner
     gc.collect()
-    checks = check_rounds(m, w, base, lora0, tap)
+    checks = check_rounds(m, w, base, lora0, tap, parts.reference)
     tokens = n_sched * counts["train_tokens"]
     return {
         "e2e": {"setup_s": cell.setup_s,
@@ -228,13 +286,40 @@ def run(cell, devices, meter) -> dict:
     }
 
 
+def readings(cell, devices, control: bool, meter,
+             parts: Parts = PARTS) -> list:
+    """The numbers the cell compares, from the set-up schedule of the
+    timed path, and with ``control`` those of the control (the reference
+    with its base rounded to float8 in the program's place) and of the
+    fault of a step that trains on half of each batch (the reference so
+    planted): ``[(what, {number: value}), ...]`` for ``calibrate.py``."""
+    m, w, ref = cell.model, cell.params, parts.reference
+    runner, base, lora0, tap = setup(cell, devices, parts)
+    del runner
+    gc.collect()
+    if not recorded(w, tap):
+        raise harness.BenchError("the set-up schedule was not recorded")
+    want = reference_rounds(m, w, base, lora0, tap, ref)
+    out = [("program", gaps(program_rounds(tap, want), want))]
+    if control:
+        out.append(("control", gaps(reference_rounds(
+            m, w, base, lora0, tap, ref, control=True), want)))
+        out.append(("half_batch", gaps(reference_rounds(
+            m, w, base, lora0, tap, ref, half_batch=True), want)))
+    out.append(("groups", [r["groups"] for r in want]))
+    out.append(("peak", harness.memory_peak_bytes(devices)))
+    del base, lora0, tap
+    jax.clear_caches()
+    return out
+
+
 # ---------------------------------------------------------------------------
 # the comparison with the reference
 # ---------------------------------------------------------------------------
 
 
-def reference_rounds(m, w, base, lora0, tap, *, control=False,
-                     half_batch=False):
+def reference_rounds(m, w, base, lora0, tap, R: ModuleType = R, *,
+                     control=False, half_batch=False):
     """The reference over the rounds that ``check_rounds`` lists, each
     started from the program's adapter after the round before it (the
     first from ``lora0``); at a stage's first round the reference makes
@@ -244,7 +329,7 @@ def reference_rounds(m, w, base, lora0, tap, *, control=False,
     eval loss, the round's input adapter, each adapter layer's change,
     and each layer's first-step gradient norm."""
     n_layers = m["num_hidden_layers"]
-    caps, per, lr_of = _schedule(m, w)
+    caps, per, lr_of = _schedule(m, w, R)
     check = set(w["check_rounds"])
     params = R.quantize(base) if control else base
     steps = jax.jit(lambda p, lo, b, lr: R.adamw_steps(m, p, lo, b, lr))
@@ -272,14 +357,14 @@ def reference_rounds(m, w, base, lora0, tap, *, control=False,
             if r in check:
                 out.append(_reference_round(
                     m, sub, sub_lora, rec, tap.evals[r], lr_of(st), steps,
-                    half_batch))
+                    half_batch, R))
                 out[-1].update(round=r, groups=groups)
             # the next round starts from what the program returned
             sub_lora = jax.tree.map(jnp.asarray, rec["lora_out"])
     return out
 
 
-def _reference_round(m, sub, sub_lora, rec, ev, lr, steps, half_batch):
+def _reference_round(m, sub, sub_lora, rec, ev, lr, steps, half_batch, R):
     batches = {k: jnp.asarray(v) for k, v in rec["batches"].items()}
     if half_batch:
         half = batches["tokens"].shape[2] // 2
@@ -373,11 +458,11 @@ def recorded(w, tap) -> bool:
         0 <= r < w["rounds"] for r in w["check_rounds"])
 
 
-def check_rounds(m, w, base, lora0, tap):
+def check_rounds(m, w, base, lora0, tap, R: ModuleType = R):
     """Each number compared, with its limit from the workload file."""
     limits = w.get("limits", {})
     if recorded(w, tap):
-        want = reference_rounds(m, w, base, lora0, tap)
+        want = reference_rounds(m, w, base, lora0, tap, R)
         g = gaps(program_rounds(tap, want), want)
     else:
         print(f"the set-up schedule ran {len(tap.rounds)} round and "

@@ -4,7 +4,8 @@
     python3 chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is ``chipbench/workloads/<name>.json``; its model configuration
-is ``chipbench/configs/<config>.json``; the metrics it reports are the
+is ``chipbench/configs/<config>.json``; its ``kind`` names the module
+that runs it, ``chipbench/<kind>_cell.py``; the metrics it reports are the
 entries of ``BENCHMARK.json`` that name it (or, for a per-layer metric
 without a ``workloads`` key, the end-to-end metric it moves), each
 per-layer one read by ``chipbench/metrics/<metric>.py``.
@@ -50,31 +51,18 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     the configuration's tiny ``rehearsal`` sizes on whatever device JAX
     has (the CPU in the tests)."""
     _paths(root)
-    import jax
-
     from chipbench import harness
 
     bench = harness.load_json(os.path.join(root, "BENCHMARK.json"))
     cell = harness.Cell(root, name, seed, seconds, trace, rehearse,
                         T_START if t_start is None else t_start)
-    chips = cell.workload["chips"]
-    if rehearse:
-        devices = jax.devices()[:chips]
-        pk = harness.peaks("TPU v5 lite", os.path.join(root, "chipbench"))
-    else:
-        devices = harness.require_tpu(chips)
-        pk = harness.peaks(devices[0].device_kind,
-                           os.path.join(root, "chipbench"))
+    devices = harness.cell_devices(cell, rehearse)
+    pk = harness.peaks("TPU v5 lite" if rehearse else devices[0].device_kind,
+                       os.path.join(root, "chipbench"))
     if not rehearse:
         harness.setup_compile_cache(root)
     meter = harness.CompileMeter()
-    kind = cell.workload["kind"]
-    if kind == "round":
-        from chipbench import round_cell as mod
-    elif kind in ("serve_closed", "serve_open"):
-        from chipbench import serve_cell as mod
-    else:
-        raise harness.BenchError(f"unknown cell kind {kind!r}")
+    mod = harness.cell_module(root, cell.workload["kind"])
 
     out = mod.run(cell, devices, meter)
     gc.collect()

@@ -1,10 +1,10 @@
 """Serve cells: ``ServingEngine.submit`` / ``ServingEngine.step`` over an
 ``AdapterRegistry`` of seeded rank-r adapters.
 
-``serve_closed``: ``clients`` callers each wait for their reply and then
-send the next request; set-up compiles the decode step and fills the
-slots, and the window ends with the first step after ``--seconds``.
-``serve_open``: requests arrive on a Poisson schedule at ``rate`` per
+``"loop": "closed"``: ``clients`` callers each wait for their reply and
+then send the next request; set-up compiles the decode step and fills
+the slots, and the window ends with the first step after ``--seconds``.
+``"loop": "open"``: requests arrive on a Poisson schedule at ``rate`` per
 second for ``--seconds``; each is timed from when it was due, and those
 due late in the window are followed to completion after it closes.
 
@@ -85,14 +85,16 @@ class Book:
         return done
 
 
+def _drive(cell, book, m, w, meter) -> dict:
+    run = _closed if cell.workload["loop"] == "closed" else _open
+    return run(cell, book, m, w, meter)
+
+
 def run(cell, devices, meter) -> dict:
     m, w = cell.model, cell.params
     engine, base, stacked = setup(cell)
     book = Book(engine)
-    if cell.workload["kind"] == "serve_closed":
-        out = _closed(cell, book, m, w, meter)
-    else:
-        out = _open(cell, book, m, w, meter)
+    out = _drive(cell, book, m, w, meter)
     out["memory_peak_bytes"] = harness.memory_peak_bytes(devices)
     finished = [book.reqs[r.rid] for r in engine.finished
                 if r.rid in book.reqs]
@@ -100,6 +102,29 @@ def run(cell, devices, meter) -> dict:
     gc.collect()
     gap = check_served(m, w, base, stacked, finished, cell.seed)["logit_gap"]
     out["checks"] = {"logit_gap": (gap, w.get("limits", {}).get("logit_gap"))}
+    return out
+
+
+def readings(cell, devices, control: bool, meter) -> list:
+    """The run's end-to-end numbers and its ``logit_gap``, and with
+    ``control`` the control's (the reference with its base rounded to
+    float8 in the engine's place), for ``calibrate.py``."""
+    m, w = cell.model, cell.params
+    engine, base, stacked = setup(cell)
+    book = Book(engine)
+    res = _drive(cell, book, m, w, meter)
+    finished = [book.reqs[r.rid] for r in engine.finished
+                if r.rid in book.reqs]
+    out = [("e2e", res["e2e"]), ("peak", harness.memory_peak_bytes(devices))]
+    del engine, book
+    gc.collect()
+    out.append(("program", check_served(m, w, base, stacked, finished,
+                                        cell.seed)))
+    if control:
+        out.append(("control", check_served(m, w, base, stacked, finished,
+                                            cell.seed, control=True)))
+    del base, stacked
+    jax.clear_caches()
     return out
 
 

@@ -3,7 +3,7 @@ the control and each fault the cell can have fail. The limits here are
 the ``rehearsal`` block's, set from readings at this size."""
 import pytest
 
-from chipbench import calibrate, harness
+from chipbench import harness, round_cell, serve_cell
 from chipbench.run import ROOT, run_cell
 
 ROUND = "qwen2-7b-l4.round.devft"
@@ -131,12 +131,14 @@ def test_the_control_fails():
     """The reference with its base rounded to float8 in the program's
     place fails a number the cell compares."""
     cell = harness.Cell(ROOT, ROUND, 22, 1.0, False, True, 0.0)
-    got = dict(calibrate.round_readings(cell, control=True))
+    got = dict(round_cell.readings(cell, harness.cell_devices(cell, True),
+                                   True, harness.CompileMeter()))
     limits = cell.params["limits"]
     assert any(v > limits[k] for k, v in got["control"].items())
     assert all(v <= limits[k] for k, v in got["program"].items())
 
     cell = harness.Cell(ROOT, SERVE, 22, 1.0, False, True, 0.0)
-    got = dict(calibrate.serve_readings(cell, True, harness.CompileMeter()))
+    got = dict(serve_cell.readings(cell, harness.cell_devices(cell, True),
+                                   True, harness.CompileMeter()))
     limit = cell.params["limits"]["logit_gap"]
     assert got["control"]["logit_gap"] > limit >= got["program"]["logit_gap"]

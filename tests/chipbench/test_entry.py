@@ -6,6 +6,7 @@ import shutil
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 from chipbench import harness
@@ -100,3 +101,15 @@ def test_cells_report_what_the_contract_asks():
         e2e = {m["name"] for m in got["end_to_end"]}
         assert "setup_s" in e2e and len(e2e) >= 2, w["name"]
         assert got["per_layer"], w["name"]
+
+
+def test_meshes_cover_their_chips():
+    """A cell that names a mesh spreads it over exactly the chips it asks
+    for; a one-chip cell names none."""
+    bench_dir = os.path.join(ROOT, "chipbench")
+    for w in BENCH["workloads"]:
+        wf = json.load(open(os.path.join(bench_dir, "workloads",
+                                         w["name"] + ".json")))
+        mesh = wf.get("mesh", {})
+        assert int(np.prod(list(mesh.values()))) == w["chips"], w["name"]
+        assert set(mesh) <= {"data", "model"}, w["name"]

@@ -85,3 +85,27 @@ def test_moe_reader_needs_the_one_expert_kernel(calls, reads):
     assert (got is not None) is reads
     if reads:
         assert 0 < got < 100
+
+
+def test_collective_share_counts_collectives_only():
+    """``train.collective_share`` on a reduced trace: every collective
+    and both halves of an asynchronous one count, nothing else does."""
+    from chipbench import harness
+    from chipbench.run import ROOT
+
+    ops = {"all-gather": 1.0, "all-gather-start": 0.5, "all-gather-done": 0.25,
+           "reduce-scatter": 0.125, "all-reduce": 2.0, "all-reduce-start": 1.0,
+           "all-reduce-done": 0.5, "collective-permute-start": 0.25,
+           "collective-permute-done": 0.125, "all-to-all": 0.25,
+           "all-reduce-scatter-fusion": 0.5,
+           "fusion": 10.0, "convolution_multiply_fusion": 3.0, "copy-start": 1.0,
+           "copy-done": 1.0, "lora_matmul": 2.0, "reduce": 1.0,
+           "scatter": 1.0, "dynamic-update-slice": 0.5}
+    reduced = {"op_s": ops, "busy_s": 40.0}
+    got = harness.read_metric(ROOT, "train.collective_share",
+                              {"trace": reduced})
+    assert got == pytest.approx(100.0 * 6.5 / 40.0)
+    # one chip, no collective: nothing to read
+    solo = {"op_s": {"fusion": 10.0, "copy-start": 1.0}, "busy_s": 12.0}
+    assert harness.read_metric(ROOT, "train.collective_share",
+                               {"trace": solo}) is None
