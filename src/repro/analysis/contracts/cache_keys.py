@@ -78,6 +78,10 @@ VARIANTS = (
     ("d_ff", 256), ("vocab", 256), ("head_dim", 32), ("qk_norm", True),
     ("qkv_bias", True), ("rope_theta", 100000.0),
     ("sliding_window", 8), ("norm_eps", 1e-5), ("tie_embeddings", True),
+    ("nope", True), ("embedding_multiplier", 12.0),
+    # 0.5, not 0.25: the proxy's default score scale is 1/sqrt(16)
+    ("attention_multiplier", 0.5), ("residual_multiplier", 0.22),
+    ("logits_scaling", 8.0),
     ("dtype", "float32"), ("arch_id", "renamed-proxy"),
     ("source", "contract-probe"),
 )

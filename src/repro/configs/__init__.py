@@ -30,6 +30,7 @@ _ARCH_MODULES = {
     "phi4-mini-3.8b": "phi4_mini_38b",
     "deepseek-v3-671b": "deepseek_v3_671b",
     "granite-moe-1b-a400m": "granite_moe_1b_a400m",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
     "whisper-tiny": "whisper_tiny",
     "qwen2-7b": "qwen2_7b",
     "llama2-7b-proxy": "llama2_7b_proxy",

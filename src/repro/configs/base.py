@@ -94,6 +94,7 @@ class ModelConfig:
     qk_norm: bool = False
     qkv_bias: bool = False
     rope_theta: float = 1e6
+    nope: bool = False              # no rotary embedding (NoPE attention)
     sliding_window: Optional[int] = None   # static window (if arch has one)
     mla: Optional[MLAConfig] = None
     # mlp / moe
@@ -110,6 +111,15 @@ class ModelConfig:
     # enc-dec
     is_encdec: bool = False
     n_enc_layers: int = 0
+    # Granite's multipliers; each is the identity at its default:
+    # embeddings times ``embedding_multiplier``, attention scores times
+    # ``attention_multiplier`` (None: 1/sqrt(head_dim)), every mixer and
+    # MLP branch times ``residual_multiplier`` before the residual add,
+    # logits divided by ``logits_scaling``
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # misc
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
@@ -148,18 +158,14 @@ class ModelConfig:
         Returns list of (stack_name, n_layers_in_stack).
         """
         if self.family == "hybrid":
+            # one attention layer per period; with experts, MoE replaces
+            # the MLP on every ``moe.every``-th layer (all Mamba layers)
             n_attn = self.n_layers // self.attn_period
-            n_mamba = self.n_layers - n_attn
-            every = self.moe.every if self.moe else 1
-            n_moe_layers = self.n_layers // every if self.moe else 0
-            # attn layers sit at even indices (offset 4, period 8) -> dense MLP
-            n_mamba_moe = n_moe_layers
-            n_mamba_mlp = n_mamba - n_mamba_moe
-            return [
-                ("mamba_mlp", n_mamba_mlp),
-                ("mamba_moe", n_mamba_moe),
-                ("attn_mlp", n_attn),
-            ]
+            n_moe = self.n_layers // self.moe.every if self.moe else 0
+            stacks = [("mamba_mlp", self.n_layers - n_attn - n_moe)]
+            if self.moe:
+                stacks.append(("mamba_moe", n_moe))
+            return stacks + [("attn_mlp", n_attn)]
         if self.is_encdec:
             return [("enc", self.n_enc_layers), ("dec", self.n_layers)]
         if self.moe and self.moe.first_dense_layers:

@@ -60,8 +60,9 @@ def build_submodel(cfg, params: dict, lora: dict, capacity: int, *,
     stacks (whisper encoder) are carried over whole. ``seed`` is an int
     or a tuple of keyed entropy (e.g. ``(base_seed, stage)``). Each
     stack that shrinks is grouped under the host span
-    ``repro.devft.group`` (args ``layers``, ``groups``) and fused under
-    ``repro.devft.fuse`` (args ``layers_in``, ``layers_out``).
+    ``repro.devft.group`` (args ``stack``, ``layers``, ``groups``) and
+    fused under ``repro.devft.fuse`` (args ``stack``, ``layers_in``,
+    ``layers_out``); each stack on its own.
     """
     sizes = stack_sizes(params["blocks"])
     shrinkable = {n: s for n, s in sizes.items() if n not in _PROTECTED}
@@ -79,11 +80,11 @@ def build_submodel(cfg, params: dict, lora: dict, capacity: int, *,
                               "n_layers": sizes[name]}
             continue
         lo = lora.get(name)
-        with TraceAnnotation("repro.devft.group", layers=sizes[name],
-                             groups=caps[name]):
+        with TraceAnnotation("repro.devft.group", stack=name,
+                             layers=sizes[name], groups=caps[name]):
             groups = make_groups(grouping, stack, lo, caps[name], seed=seed)
-        with TraceAnnotation("repro.devft.fuse", layers_in=sizes[name],
-                             layers_out=caps[name]):
+        with TraceAnnotation("repro.devft.fuse", stack=name,
+                             layers_in=sizes[name], layers_out=caps[name]):
             new_blocks[name] = fuse_stack(stack, groups, beta, fusion,
                                           seed=seed)
             if lo is not None:

@@ -11,6 +11,8 @@ leading layer axis (the representation used by ``repro.models``).
 """
 from __future__ import annotations
 
+import functools
+import math
 from typing import List, Optional, Sequence
 
 import jax
@@ -32,19 +34,32 @@ def layer_vectors(stack: dict, lora_stack: Optional[dict] = None,
     their corresponding LoRA parameters"). For very wide layers a
     deterministic stride subsample caps D at ``max_elems`` — cosine
     similarity is preserved in expectation and this keeps the server-side
-    grouping cheap even at 671B scale.
+    grouping cheap even at 671B scale. The subsample is every
+    ``stride``-th element of the layer's leaves laid end to end, read out
+    of each leaf where it lies, so no full-width float32 copy of the
+    stack is made.
     """
     leaves = list(jax.tree.leaves(stack))
     if lora_stack is not None:
         leaves += list(jax.tree.leaves(lora_stack))
-    L = leaves[0].shape[0]
-    flats = [jnp.reshape(x.astype(jnp.float32), (L, -1)) for x in leaves]
-    vec = jnp.concatenate(flats, axis=1)
-    d = vec.shape[1]
-    if d > max_elems:
-        stride = -(-d // max_elems)
-        vec = vec[:, ::stride]
-    return vec
+    sizes = [math.prod(x.shape[1:]) for x in leaves]
+    d = sum(sizes)
+    stride = -(-d // max_elems) if d > max_elems else 1
+    parts, offset = [], 0
+    for x, n in zip(leaves, sizes):
+        start = -offset % stride
+        if start < n:
+            parts.append(_strided(x, start, stride))
+        offset += n
+    return jnp.concatenate(parts, axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("start", "stride"))
+def _strided(x, start: int, stride: int) -> jax.Array:
+    """Elements ``start, start + stride, ...`` of each layer of a leaf,
+    as float32 (L, n)."""
+    return jnp.reshape(x, (x.shape[0], -1))[:, start::stride].astype(
+        jnp.float32)
 
 
 def similarity_matrix(vecs: jax.Array) -> jax.Array:

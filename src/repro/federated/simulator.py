@@ -52,15 +52,17 @@ the first round's batches), one ``repro.round`` step per round (a
 ``StepTraceAnnotation``; args ``stage``, ``capacity``, ``clients``,
 ``tokens``) and ``repro.run.finalize``. A round holds, in order,
 ``repro.stage.enter`` (at a stage's first round; args ``stage``,
-``capacity``; DevFT's ``repro.devft.transfer``, ``repro.devft.group``
-and ``repro.devft.fuse`` nest in it), ``repro.round.plan``,
-``repro.round.place`` (arg ``bytes``), ``repro.round.dispatch``,
-``repro.eval.dispatch``, ``repro.round.host_batches`` (the prefetch;
-arg ``bytes``), ``repro.round.fetch`` (the blocking read of the round
-before's eval scalars; the last one follows the loop) and
-``repro.round.books``. Device-side, ``jax.named_scope`` marks
-``local_train`` (with ``loss_and_grad`` and ``adamw``), ``aggregate``
-and ``eval`` in the HLO metadata.
+``capacity``, and on a hybrid model the stage's ``mamba_layers`` and
+``attn_layers``; DevFT's ``repro.devft.transfer``, and each stack's
+``repro.devft.group`` and ``repro.devft.fuse`` (arg ``stack``) nest in
+it), ``repro.round.plan``, ``repro.round.place`` (arg ``bytes``),
+``repro.round.dispatch``, ``repro.eval.dispatch``,
+``repro.round.host_batches`` (the prefetch; arg ``bytes``),
+``repro.round.fetch`` (the blocking read of the round before's eval
+scalars; the last one follows the loop) and ``repro.round.books``.
+Device-side, ``jax.named_scope`` marks ``local_train`` (with
+``loss_and_grad`` and ``adamw``), ``aggregate`` and ``eval`` in the HLO
+metadata.
 
 Cost accounting (per paper §4.4):
 * communication — exact bytes of transmitted LoRA tensors, up + down,
@@ -155,7 +157,7 @@ ROUND_DONATE_ARGNUMS = (1,)
 
 
 def make_round_program(strategy, run_state, sub_cfg, n_sample, *,
-                       hetero: bool):
+                       hetero: bool, remat: bool = False):
     """Build the (untraced) round program: vmapped K-step local training
     plus the strategy's (registry-dispatched) server aggregation, as ONE
     function to be jitted. Returns ``(round_fn, aux)`` where
@@ -168,9 +170,10 @@ def make_round_program(strategy, run_state, sub_cfg, n_sample, *,
 
     Heterogeneous programs add two traced operands: per-client step
     masks ``(C, K)`` realizing ragged local work inside the scan, and
-    the per-client aggregation-weight vector ``(C,)``.
+    the per-client aggregation-weight vector ``(C,)``. ``remat``
+    rematerialises each layer in the local backward.
     """
-    local = make_local_train(sub_cfg)
+    local = make_local_train(sub_cfg, remat=remat)
     aux: Dict = {}
 
     if hetero:
@@ -233,6 +236,14 @@ def _round_flops(params, total_steps, batch, seq) -> float:
     return _step_flops(params, batch, seq) * total_steps
 
 
+def _hybrid_depths(params) -> Dict[str, int]:
+    """A hybrid (sub)model's Mamba and attention layer counts."""
+    sizes = T.stack_sizes(params["blocks"])
+    return {"mamba_layers": sizes.get("mamba_mlp", 0)
+            + sizes.get("mamba_moe", 0),
+            "attn_layers": sizes.get("attn_mlp", 0)}
+
+
 def _memory_bytes(params, lora, batch, seq, cfg) -> int:
     """Per-device bytes: submodel params + LoRA + Adam moments + a rough
     activation estimate scaled by the *submodel's* depth and width (a
@@ -249,15 +260,19 @@ class FederatedRunner:
 
     ``mesh=None`` (default) executes on the default device; passing a
     mesh shards the same round program over it (see module docstring).
+    ``remat=True`` rematerialises each layer in the local backward, for
+    a model whose local step does not fit the device otherwise.
     """
 
     def __init__(self, cfg, fed: FedConfig, data: FederatedData, *,
-                 dtype=jnp.float32, params=None, mesh=None):
+                 dtype=jnp.float32, params=None, mesh=None,
+                 remat: bool = False):
         cfg = mesh_kernel_backend(cfg, mesh)
         self.cfg = cfg
         self.fed = fed
         self.data = data
         self.mesh = mesh
+        self.remat = remat
         self.strategy = make_strategy(fed.method, cfg, fed)
         if fed.straggler_policy not in POLICIES:
             raise ValueError(f"unknown straggler_policy "
@@ -320,7 +335,7 @@ class FederatedRunner:
         if key not in self._round_fn_cache:
             round_fn, aux = make_round_program(
                 self.strategy, self._run_state, sub_cfg, self._n_sample,
-                hetero=self._hetero)
+                hetero=self._hetero, remat=self.remat)
             if self.mesh is not None:
                 # donate the per-round adapter buffers: new_lora aliases
                 # the incoming LoRA tree (the per-client stacks and opt
@@ -460,8 +475,11 @@ class FederatedRunner:
                 stage_entry = stage != stage_prev
                 if stage_entry:
                     with TraceAnnotation("repro.stage.enter", stage=stage,
-                                         capacity=capn):
+                                         capacity=capn) as sp:
                         strat.on_stage(state, stage)
+                        if self.cfg.family == "hybrid":
+                            sp.set_metadata(**_hybrid_depths(
+                                strat.local_spec(state).params))
                     stage_prev = stage
                 with TraceAnnotation("repro.round.plan"):
                     spec = strat.local_spec(state)

@@ -152,5 +152,6 @@ def ssd_scan_bhsp(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         out_shape=jax.ShapeDtypeStruct((bsz, h, s_pad, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(x, dt2, b, c, a2, d2)
     return y[:, :, :s]

@@ -261,7 +261,8 @@ def lora_scaling(lora) -> float:
 
 def gqa_qkv(params: dict, cfg, x: jax.Array, cos, sin, lora=None,
             backend: str = "reference"):
-    """Project to rotated q, k, v. lora: optional {'wq': {a,b}, 'wv': {a,b}}."""
+    """Project to q, k, v, rotated unless the config is NoPE (``cos`` and
+    ``sin`` are then unused). lora: optional {'wq': {a,b}, 'wv': {a,b}}."""
     b, s, _ = x.shape
     h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     lq = lora.get("wq") if lora else None
@@ -274,8 +275,9 @@ def gqa_qkv(params: dict, cfg, x: jax.Array, cos, sin, lora=None,
     if cfg.qk_norm:
         q = rms_norm(q, params["q_norm"], cfg.norm_eps)
         k = rms_norm(k, params["k_norm"], cfg.norm_eps)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if not cfg.nope:
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     return q, k, v
 
 
@@ -283,7 +285,8 @@ def gqa_attention(params: dict, cfg, x: jax.Array, cos, sin, *,
                   window=None, lora=None, causal=True) -> jax.Array:
     backend = model_backend(cfg)
     q, k, v = gqa_qkv(params, cfg, x, cos, sin, lora=lora, backend=backend)
-    out = attend(q, k, v, causal=causal, window=window, backend=backend)
+    out = attend(q, k, v, causal=causal, window=window,
+                 scale=cfg.attention_multiplier, backend=backend)
     b, s, _, _ = q.shape
     return out.reshape(b, s, -1) @ params["wo"]
 
@@ -306,7 +309,7 @@ def gqa_decode(params: dict, cfg, x: jax.Array, cache: dict, pos, cos, sin, *,
     # ring buffer holds the last `cap` tokens -> all slots valid once full
     valid = jnp.minimum(pos + 1, cap)
     fd = dispatch.get_kernel("flash_decode", model_backend(cfg))
-    out = fd(q, k, v, kv_valid_len=valid,
+    out = fd(q, k, v, kv_valid_len=valid, scale=cfg.attention_multiplier,
              interpret=dispatch.interpret_default())
     b, s = x.shape[:2]
     y = out.reshape(b, s, -1) @ params["wo"]

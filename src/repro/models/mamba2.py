@@ -133,8 +133,10 @@ def ssd_chunked(x, dt, A, B, C, D, chunk: int):
     return y.reshape(b, S, H, P)
 
 
+@jax.named_scope("mamba_mixer")
 def mamba_forward(params: dict, cfg, u: jax.Array, *, lora=None) -> jax.Array:
-    """Full-sequence forward. u: (B, S, d_model)."""
+    """Full-sequence forward. u: (B, S, d_model). Its operations carry
+    the device-side name ``mamba_mixer`` in the HLO metadata."""
     mb = cfg.mamba
     din, h = d_inner(cfg), n_heads(cfg)
     backend = model_backend(cfg)

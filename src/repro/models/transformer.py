@@ -55,8 +55,9 @@ def _maybe_scan(body, init, xs):
 def stack_kinds(cfg) -> Dict[str, str]:
     """stack name -> block kind."""
     if cfg.family == "hybrid":
-        return {"mamba_mlp": "mamba_mlp", "mamba_moe": "mamba_moe",
-                "attn_mlp": "gqa_mlp"}
+        return {name: {"mamba_mlp": "mamba_mlp", "mamba_moe": "mamba_moe",
+                       "attn_mlp": "gqa_mlp"}[name]
+                for name, _ in cfg.layer_stacks()}
     if cfg.is_encdec:
         return {"enc": "enc", "dec": "dec"}
     if cfg.moe is not None and cfg.moe.first_dense_layers:
@@ -76,17 +77,24 @@ def stack_sizes(blocks: dict) -> Dict[str, int]:
             for name, stack in blocks.items()}
 
 
-def hybrid_order(sizes: Dict[str, int]):
-    """Deterministic interleave for (sub)models of the hybrid family:
-    attention layers evenly spaced with the canonical period//2 offset
-    (reproduces Jamba's 1-in-8-at-offset-4 for the full model), MoE on
-    alternating mamba slots (reproduces MoE-every-2). Works for any stack
-    sizes, which is what lets DEVFT submodels execute."""
+def hybrid_order(sizes: Dict[str, int], attn_period: int, attn_offset: int):
+    """Deterministic interleave for (sub)models of the hybrid family.
+
+    The model's ``at`` attention layers split its depth into periods of
+    ``total // at`` layers, with one attention layer in each at the
+    config's offset scaled to that period (``attn_offset * period //
+    attn_period``). The full model's period is the config's, so its
+    attention sits exactly where the config puts it (Jamba: 4 of 8;
+    Granite 4.0-H: 5 of 10); a DEVFT submodel with fewer layers keeps
+    one attention layer per period at the same relative place. MoE takes
+    alternating mamba slots (reproduces MoE-every-2). Works for any
+    stack sizes, which is what lets DEVFT submodels execute."""
     mm, mo, at = (sizes.get("mamba_mlp", 0), sizes.get("mamba_moe", 0),
                   sizes.get("attn_mlp", 0))
     total = mm + mo + at
     period = max(total // max(at, 1), 1)
-    attn_pos = {k * period + period // 2 for k in range(at)}
+    offset = attn_offset * period // attn_period
+    attn_pos = {k * period + offset for k in range(at)}
     order, c = [], {"mamba_mlp": 0, "mamba_moe": 0, "attn_mlp": 0}
     for i in range(total):
         if i in attn_pos and c["attn_mlp"] < at:
@@ -100,6 +108,18 @@ def hybrid_order(sizes: Dict[str, int]):
     return order
 
 
+def hybrid_runs(order):
+    """``execution_order``'s ``(stack, index)`` list as runs of
+    consecutive layers of one stack: ``[(stack, start, stop), ...]``."""
+    runs = []
+    for name, idx in order:
+        if runs and runs[-1][0] == name and runs[-1][2] == idx:
+            runs[-1][2] = idx + 1
+        else:
+            runs.append([name, idx, idx + 1])
+    return [tuple(r) for r in runs]
+
+
 def execution_order(cfg, sizes: Optional[Dict[str, int]] = None):
     """List of (stack_name, index_within_stack) in layer execution order.
 
@@ -109,7 +129,7 @@ def execution_order(cfg, sizes: Optional[Dict[str, int]] = None):
     if sizes is None:
         sizes = dict(cfg.layer_stacks())
     if cfg.family == "hybrid":
-        return hybrid_order(sizes)
+        return hybrid_order(sizes, cfg.attn_period, cfg.attn_offset)
     out = []
     for name, _ in cfg.layer_stacks():
         out.extend((name, i) for i in range(sizes.get(name, 0)))
@@ -180,12 +200,20 @@ def _ffn(p, cfg, kind, x, *, moe_path="gather", mesh=None):
     return Lyr.mlp(p["ffn"], x), jnp.zeros((), jnp.float32)
 
 
+def _branch(cfg, y):
+    """A mixer's or MLP's output as it joins the residual stream (times
+    ``residual_multiplier``; no operation at its default of 1)."""
+    return y if cfg.residual_multiplier == 1.0 \
+        else y * cfg.residual_multiplier
+
+
 def block_forward(p, cfg, kind, x, cos, sin, lora=None, *, window=None,
                   causal=True, enc_out=None, moe_path="gather", mesh=None):
     """Pre-norm residual block. Returns (y, aux)."""
     h = Lyr.rms_norm(x, p["ln1"], cfg.norm_eps)
     if kind == "mamba_only":
-        return x + Mb.mamba_forward(p["mixer"], cfg, h, lora=lora), \
+        return x + _branch(cfg, Mb.mamba_forward(p["mixer"], cfg, h,
+                                                 lora=lora)), \
             jnp.zeros((), jnp.float32)
     if kind.startswith("mamba"):
         mix = Mb.mamba_forward(p["mixer"], cfg, h, lora=lora)
@@ -195,7 +223,7 @@ def block_forward(p, cfg, kind, x, cos, sin, lora=None, *, window=None,
     else:
         mix = Lyr.gqa_attention(p["mixer"], cfg, h, cos, sin, lora=lora,
                                 window=window, causal=causal)
-    x = x + mix
+    x = x + _branch(cfg, mix)
     if kind == "dec" and enc_out is not None:
         hx = Lyr.rms_norm(x, p["lnx"], cfg.norm_eps)
         q, _, _ = Lyr.gqa_qkv(p["cross"], cfg, hx, cos * 0 + 1, sin * 0,
@@ -206,7 +234,7 @@ def block_forward(p, cfg, kind, x, cos, sin, lora=None, *, window=None,
         x = x + cx.reshape(x.shape[0], x.shape[1], -1) @ p["cross"]["wo"]
     h2 = Lyr.rms_norm(x, p["ln2"], cfg.norm_eps)
     y, aux = _ffn(p, cfg, kind, h2, moe_path=moe_path, mesh=mesh)
-    return x + y, aux
+    return x + _branch(cfg, y), aux
 
 
 def block_decode(p, cfg, kind, x, cache, pos, cos, sin, lora=None, *,
@@ -215,7 +243,7 @@ def block_decode(p, cfg, kind, x, cache, pos, cos, sin, lora=None, *,
     h = Lyr.rms_norm(x, p["ln1"], cfg.norm_eps)
     if kind == "mamba_only":
         mix, nc = Mb.mamba_decode(p["mixer"], cfg, h, cache, lora=lora)
-        return x + mix, nc, jnp.zeros((), jnp.float32)
+        return x + _branch(cfg, mix), nc, jnp.zeros((), jnp.float32)
     if kind.startswith("mamba"):
         mix, nc = Mb.mamba_decode(p["mixer"], cfg, h, cache["mixer"], lora=lora)
         nc = {"mixer": nc}
@@ -227,7 +255,7 @@ def block_decode(p, cfg, kind, x, cache, pos, cos, sin, lora=None, *,
         mix, nc_attn = Lyr.gqa_decode(p["mixer"], cfg, h, cache["mixer"],
                                       pos, cos, sin, lora=lora)
         nc = {"mixer": nc_attn}
-    x = x + mix
+    x = x + _branch(cfg, mix)
     if kind == "dec":
         hx = Lyr.rms_norm(x, p["lnx"], cfg.norm_eps)
         q, _, _ = Lyr.gqa_qkv(p["cross"], cfg, hx, cos * 0 + 1, sin * 0)
@@ -237,7 +265,7 @@ def block_decode(p, cfg, kind, x, cache, pos, cos, sin, lora=None, *,
         nc["cross_k"], nc["cross_v"] = ek, ev
     h2 = Lyr.rms_norm(x, p["ln2"], cfg.norm_eps)
     y, aux = _ffn(p, cfg, kind, h2, moe_path=moe_path, mesh=mesh)
-    return x + y, nc, aux
+    return x + _branch(cfg, y), nc, aux
 
 
 def _init_block_cache(cfg, kind, batch, capacity, dtype):
@@ -328,11 +356,19 @@ def _lora_for(lora, stack_name):
 # ---------------------------------------------------------------------------
 
 
+def _embed(cfg, params, tokens):
+    """Token embeddings, times ``embedding_multiplier`` where it is not
+    its default of 1."""
+    x = params["embed"][tokens]
+    return x if cfg.embedding_multiplier == 1.0 \
+        else x * cfg.embedding_multiplier
+
+
 def _embed_inputs(cfg, params, batch):
     """Returns (x (B,S,d), cos, sin, n_prefix) — prefix = frontend tokens."""
     tokens = batch["tokens"]
     b, s_text = tokens.shape
-    x = params["embed"][tokens]
+    x = _embed(cfg, params, tokens)
     n_prefix = 0
     if cfg.frontend == "vision" and "vision_embeds" in batch:
         ve = batch["vision_embeds"].astype(x.dtype) @ params["vis_proj"]
@@ -345,8 +381,8 @@ def _embed_inputs(cfg, params, batch):
                 jnp.arange(s, dtype=jnp.int32)[None, None], (3, b, s))
         cos, sin = Lyr.mrope_cos_sin(pos3, cfg.mrope_sections, cfg.hd,
                                      cfg.rope_theta)
-    elif cfg.attn_kind == "none":
-        cos = sin = None  # pure SSM: no rotary needed
+    elif cfg.attn_kind == "none" or cfg.nope:
+        cos = sin = None  # pure SSM or NoPE attention: no rotary
     else:
         rope_dim = cfg.mla.qk_rope_head_dim if cfg.attn_kind == "mla" else cfg.hd
         cos, sin = Lyr.rope_cos_sin(Lyr.text_positions(b, s), rope_dim,
@@ -382,6 +418,30 @@ def _run_stack(cfg, stack_params, kind, x, cos, sin, stack_lora, *,
     lo = stack_lora if stack_lora is not None else _none_like(stack_params, n)
     (x, aux), _ = _maybe_scan(body, (x, jnp.zeros((), jnp.float32)),
                               (stack_params, lo))
+    return x, aux
+
+
+def _run_layers(cfg, stack_params, kind, x, cos, sin, stack_lora, start,
+                stop, *, window=None, moe_path="gather", mesh=None,
+                remat=False):
+    """Layers ``start`` to ``stop - 1`` of a stack, as a scan over their
+    indices: each step reads its layer out of the whole stack, so no
+    copy of the run's weights is made. Returns (x, total_aux)."""
+
+    def layer(t, i):
+        return None if t is None else jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), t)
+
+    def body(carry, i):
+        xc, aux = carry
+        y, a = block_forward(layer(stack_params, i), cfg, kind, xc, cos, sin,
+                             layer(stack_lora, i), window=window,
+                             moe_path=moe_path, mesh=mesh)
+        return (y, aux + a), None
+
+    body = _remat_wrap(body, remat)
+    (x, aux), _ = _maybe_scan(body, (x, jnp.zeros((), jnp.float32)),
+                              jnp.arange(start, stop))
     return x, aux
 
 
@@ -447,19 +507,15 @@ def forward_hidden(cfg, params, lora, batch, *, window=None,
             total_aux, n_prefix
 
     if cfg.family == "hybrid":
-        # interleaved execution: unrolled python loop with stack slicing
-        for name, idx in execution_order(cfg, stack_sizes(params["blocks"])):
-            p = jax.tree.map(lambda a: a[idx], params["blocks"][name])
-            lo = _lora_for(lora, name)
-            lo = None if lo is None else jax.tree.map(lambda a: a[idx], lo)
-            kind = stack_kinds(cfg)[name]
-            fwd = functools.partial(
-                block_forward, p, cfg, kind, window=window,
-                moe_path=moe_path, mesh=mesh)
-            if remat:
-                fwd = _remat_wrap(
-                    lambda xx, cc, ss, ll, f=fwd: f(xx, cc, ss, ll), remat)
-            x, aux = fwd(x, cos, sin, lo)
+        # interleaved execution: each run of consecutive layers of one
+        # stack is a scan over those layers' indices
+        kinds = stack_kinds(cfg)
+        for name, start, stop in hybrid_runs(
+                execution_order(cfg, stack_sizes(params["blocks"]))):
+            x, aux = _run_layers(cfg, params["blocks"][name], kinds[name],
+                                 x, cos, sin, _lora_for(lora, name),
+                                 start, stop, window=window,
+                                 moe_path=moe_path, mesh=mesh, remat=remat)
             total_aux = total_aux + aux
         return Lyr.rms_norm(x, params["final_norm"], cfg.norm_eps), \
             total_aux, n_prefix
@@ -476,7 +532,9 @@ def forward_hidden(cfg, params, lora, batch, *, window=None,
 
 def logits_from_hidden(cfg, params, h):
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return h @ w
+    logits = h @ w
+    return logits if cfg.logits_scaling == 1.0 \
+        else logits / cfg.logits_scaling
 
 
 def loss_fn(cfg, params, lora, batch, *, window=None, moe_path="gather",
@@ -534,12 +592,14 @@ def prefill(cfg, params, lora, batch, *, window=None, moe_path="gather",
 def decode_step(cfg, params, lora, token, cache, *, moe_path="gather",
                 mesh=None):
     """One-token decode. token: (B, 1) int32. Returns (logits, new_cache)."""
-    x = params["embed"][token]
+    x = _embed(cfg, params, token)
     b = token.shape[0]
     pos = cache["pos"]
     rope_dim = cfg.mla.qk_rope_head_dim if cfg.attn_kind == "mla" else \
         (cfg.hd if cfg.n_heads else 0)
-    if rope_dim:
+    if cfg.nope:
+        cos = sin = None
+    elif rope_dim:
         if cfg.mrope:
             p3 = jnp.broadcast_to(pos[None, :, None], (3, b, 1))
             cos, sin = Lyr.mrope_cos_sin(p3, cfg.mrope_sections, cfg.hd,

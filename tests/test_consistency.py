@@ -15,7 +15,8 @@ from repro.models import transformer as T
 
 @pytest.mark.parametrize("arch", ["qwen2-7b", "qwen3-32b", "minicpm-2b",
                                   "granite-moe-1b-a400m", "mamba2-2.7b",
-                                  "deepseek-v3-671b", "jamba-v0.1-52b"])
+                                  "deepseek-v3-671b", "jamba-v0.1-52b",
+                                  "granite-4.0-h-micro"])
 def test_decode_matches_forward(arch, rng, test_spec):
     cfg = reduce_config(get_config(arch), test_spec)
     if cfg.moe is not None:

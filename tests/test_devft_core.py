@@ -42,6 +42,23 @@ def test_similarity_matrix_properties(rng):
     assert np.all(np.abs(np.asarray(w)) <= 1.0 + 1e-6)
 
 
+@pytest.mark.parametrize("max_elems", [1 << 20, 300, 37, 7, 1])
+def test_layer_vectors_subsample_every_stride_th_element(rng, max_elems):
+    """The subsample is every ``stride``-th element of each layer's
+    leaves (and LoRA) laid end to end, read out of each leaf in place:
+    the same vector as concatenating all of them in float32 first."""
+    stack = dict(_stack(rng, d=12), h=jax.random.normal(
+        jax.random.fold_in(rng, 2), (8, 3, 5), jnp.bfloat16))
+    lora = {"a": jax.random.normal(jax.random.fold_in(rng, 3), (8, 11))}
+    full = jnp.concatenate([x.astype(jnp.float32).reshape(8, -1) for x in
+                            jax.tree.leaves(stack) + jax.tree.leaves(lora)],
+                           axis=1)
+    want = full[:, ::-(-full.shape[1] // max_elems)] \
+        if full.shape[1] > max_elems else full
+    got = layer_vectors(stack, lora, max_elems=max_elems)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_spectral_grouping_partitions(rng):
     w = similarity_matrix(layer_vectors(_stack(rng)))
     for g in [1, 2, 3, 8]:

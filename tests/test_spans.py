@@ -227,3 +227,27 @@ def test_named_scopes_reach_the_hlo_metadata(test_spec):
         jnp.zeros((n, 1), jnp.int32), cache,
         jnp.zeros((n,), bool)).compile().as_text()
     assert "decode" in _scopes(text)
+
+
+def test_hybrid_stage_entries_carry_each_stacks_depth(tmp_path, test_spec):
+    """On a hybrid, a stage entry counts its Mamba and attention layers,
+    and each stack is grouped and fused under spans that name it."""
+    cfg = dataclasses.replace(reduce_config(
+        get_config("granite-4.0-h-micro"), test_spec), n_layers=20)
+    data = make_federated_data(cfg.vocab, n_clients=4, alpha=0.5, seed=0)
+    fed = FedConfig(n_clients=4, sample_frac=0.5, k_local=1, local_batch=1,
+                    seq=16, rounds=2, lora_rank=2, lr=1e-3, method="devft",
+                    n_stages=2)
+    runner = FederatedRunner(cfg, fed, data)
+    runner.run()                       # compile outside the trace
+    _, spans = _traced(tmp_path, runner.run)
+    enters = _named(spans, "repro.stage.enter")
+    assert [(e.args["capacity"], e.args["mamba_layers"],
+             e.args["attn_layers"]) for e in enters] == [(10, 9, 1),
+                                                         (20, 18, 2)]
+    group = sorted((s.args["stack"], s.args["layers"], s.args["groups"])
+                   for s in _named(spans, "repro.devft.group"))
+    assert group == [("attn_mlp", 2, 1), ("mamba_mlp", 18, 9)]
+    fuse = sorted((s.args["stack"], s.args["layers_in"], s.args["layers_out"])
+                  for s in _named(spans, "repro.devft.fuse"))
+    assert fuse == group

@@ -174,3 +174,35 @@ def test_kernel_names_in_the_round_and_decode_programs(one_chip,
         jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip)) \
         .compile().as_text()
     assert _kernel_names(text) == {"flash_decode", "moe_expert_ffn"}
+
+
+def test_kernel_names_in_the_hybrid_round_program(one_chip, monkeypatch):
+    """The hybrid cell's local training step at its widths (Granite
+    4.0-H: Mamba-2 of 64 heads of 64, state 128, chunk 256; NoPE GQA of
+    32/8 heads of 64; one whole period of 10 layers, each rematerialised;
+    two clients of 2 x 512 tokens, vmapped as the round program runs
+    them) holds the SSD kernel under its own name, ``ssd_scan``, beside
+    ``lora_matmul`` and ``flash_attention``."""
+    from repro.configs import get_config
+    from repro.federated.client import make_local_train
+
+    monkeypatch.setattr(dispatch, "interpret_default",
+                        lambda platform=None: False)
+    cfg = dataclasses.replace(get_config("granite-4.0-h-micro"), n_layers=10,
+                              kernel_backend="pallas")
+
+    def shapes(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    key = jax.random.PRNGKey(0)
+    params = shapes(jax.eval_shape(lambda: T.init_params(cfg, key, BF16)))
+    lora = shapes(jax.eval_shape(lambda: T.init_lora(cfg, key, rank=32)))
+    tok = jax.ShapeDtypeStruct((2, 1, 2, 512), jnp.int32, sharding=one_chip)
+    local = make_local_train(cfg, remat=True)
+    train = jax.jit(lambda p, l, b: jax.vmap(
+        lambda bt: local(p, l, bt, 1e-4))(b))
+    text = train.lower(params, lora, {"tokens": tok, "labels": tok}) \
+        .compile().as_text()
+    assert _kernel_names(text) == {"flash_attention", "lora_matmul",
+                                   "ssd_scan"}
