@@ -11,7 +11,8 @@ DESIGN.md §10.
 
 GQA K/V heads are NOT repeated here — ``flash_attention_bhsd`` indexes
 kv heads inside its grid, so (B,S,Hkv,D) tensors go to the kernel
-as-is and repeated heads never touch HBM.
+as-is and repeated heads never touch HBM. The SSD scan likewise takes
+the model layout whole: B/C stay (B,S,G,N), indexed by group.
 """
 from __future__ import annotations
 
@@ -28,7 +29,7 @@ from repro.kernels.flash_decode import decode_layout, flash_decode_bhrd
 from repro.kernels.lora_matmul import lora_layout
 from repro.kernels.lora_matmul import lora_matmul as _lora_matmul
 from repro.kernels.moe_ffn import moe_expert_ffn_ecd, moe_ffn_layout
-from repro.kernels.ssd_scan import ssd_layout, ssd_scan_bhsp
+from repro.kernels.ssd_scan import ssd_layout, ssd_scan_bshp
 
 
 # ---------------------------------------------------------------------------
@@ -82,18 +83,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def _ssd(x, dt, a, b, c, d, chunk, interpret):
-    h = x.shape[2]
-    g = b.shape[2]
-    rep = h // g
-    bt = jnp.repeat(jnp.swapaxes(b, 1, 2), rep, axis=1)   # (B,H,S,N)
-    ct = jnp.repeat(jnp.swapaxes(c, 1, 2), rep, axis=1)
-    xt = jnp.swapaxes(x, 1, 2)
-    dtt = jnp.swapaxes(dt, 1, 2)
-    # chunk capping / ragged-seq padding live in ssd_scan_bhsp (it owns
-    # the block layout; see ssd_layout)
-    y = ssd_scan_bhsp(xt, dtt, a, bt, ct, d, chunk=chunk,
-                      interpret=interpret)
-    return jnp.swapaxes(y, 1, 2)
+    # the kernel reads the model layout: B/C by group, chunk capping and
+    # ragged-seq padding in ssd_scan_bshp (it owns the block layout; see
+    # ssd_layout)
+    return ssd_scan_bshp(x, dt, a, b, c, d, chunk=chunk,
+                         interpret=interpret)
 
 
 def _ssd_fwd(x, dt, a, b, c, d, chunk, interpret):
@@ -256,10 +250,9 @@ def lora_matmul_layout(x, w, a, b, **kwargs):
 
 
 def ssd_scan_layout(x, dt, a, b, c, d, **kwargs):
-    """BlockLayout of ``ssd_scan`` for model-layout avals (the kernel
-    sees GQA-repeated B/C, so N groups drop out of the layout)."""
+    """BlockLayout of ``ssd_scan`` for model-layout avals."""
     bsz, s, h, p = x.shape
-    return ssd_layout(bsz, h, s, p, b.shape[-1], x.dtype,
+    return ssd_layout(bsz, s, h, p, b.shape[2], b.shape[3], x.dtype,
                       chunk=kwargs.get("chunk", 128))
 
 

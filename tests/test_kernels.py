@@ -1,5 +1,6 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles
 (interpret=True executes the kernel bodies in Python on CPU)."""
+import functools
 import math
 
 import jax
@@ -11,6 +12,8 @@ from repro.analysis.lowered.layout_lint import lint_layout
 from repro.kernels import ops, ref
 from repro.kernels.common import VMEM_BUDGET_BYTES
 from repro.kernels.lora_matmul import lora_layout
+from repro.kernels.ssd_scan import (SPLIT, _split, ssd_block_group,
+                                     ssd_layout)
 
 
 def _assert_close(got, want, dtype):
@@ -70,16 +73,10 @@ def test_flash_attention_unequal_blocks_keep_all_keys():
 # SSD scan (Mamba-2)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("s,hh,p,n,g,chunk", [
-    (64, 4, 16, 8, 2, 16),
-    (64, 2, 32, 16, 1, 32),
-    (48, 4, 16, 8, 4, 16),   # padding path (48 % 16 == 0 but chunk=16)
-    (50, 2, 16, 8, 2, 16),   # ragged seq -> pad
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_ssd_scan(s, hh, p, n, g, chunk, dtype):
-    key = jax.random.PRNGKey(s * hh)
-    b = 2
+def _ssd_operands(b, s, hh, p, n, g, dtype=jnp.float32, seed=0):
+    """Model-layout SSD operands: x (B,S,H,P), dt (B,S,H), a (H,),
+    B/C (B,S,G,N), d (H,)."""
+    key = jax.random.PRNGKey(seed)
     x = (jax.random.normal(key, (b, s, hh, p)) * 0.5).astype(dtype)
     dt = jax.nn.softplus(jax.random.normal(jax.random.fold_in(key, 1),
                                            (b, s, hh)))
@@ -89,6 +86,22 @@ def test_ssd_scan(s, hh, p, n, g, chunk, dtype):
     cc = (jax.random.normal(jax.random.fold_in(key, 4), (b, s, g, n)) * 0.5
           ).astype(dtype)
     d = jax.random.normal(jax.random.fold_in(key, 5), (hh,))
+    return x, dt, a, bb, cc, d
+
+
+@pytest.mark.parametrize("s,hh,p,n,g,chunk", [
+    (64, 4, 16, 8, 2, 16),
+    (64, 2, 32, 16, 1, 32),
+    (48, 4, 16, 8, 4, 16),   # padding path (48 % 16 == 0 but chunk=16)
+    (50, 2, 16, 8, 2, 16),   # ragged seq -> pad
+    (50, 8, 64, 16, 1, 16),  # G=1, H=8: one block of all 8 heads
+    (40, 64, 64, 16, 1, 16),  # G=1, H=64 (Granite's heads): 2 blocks of 32
+    (50, 8, 64, 128, 2, 16),  # G=2: a block of 4 heads per group
+    (50, 4, 64, 8, 4, 16),   # G=H: one head a step, group-major B/C
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_ssd_scan(s, hh, p, n, g, chunk, dtype):
+    x, dt, a, bb, cc, d = _ssd_operands(2, s, hh, p, n, g, dtype, s * hh)
     got = ops.ssd_scan(x, dt, a, bb, cc, d, chunk=chunk, interpret=True)
     bt = jnp.repeat(jnp.swapaxes(bb, 1, 2), hh // g, 1)
     ct = jnp.repeat(jnp.swapaxes(cc, 1, 2), hh // g, 1)
@@ -98,6 +111,109 @@ def test_ssd_scan(s, hh, p, n, g, chunk, dtype):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(jnp.swapaxes(want, 1, 2),
                                           np.float32), rtol=tol, atol=tol)
+    # and the chunked form the model's reference backend runs
+    f32 = [t.astype(jnp.float32) for t in (x, bb, cc)]
+    chunked = ref.ssd_scan_bshp_chunked_ref(f32[0], dt, a, f32[1], f32[2], d,
+                                            chunk=chunk)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(chunked), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("s,hh,p,n,g", [
+    (50, 8, 64, 16, 1),
+    (50, 8, 64, 128, 2),
+    (50, 4, 64, 8, 4),
+])
+def test_ssd_scan_grad_is_the_chunked_reference_vjp(s, hh, p, n, g):
+    """The kernel's backward is the jnp chunked form's VJP on the same
+    residuals: under a cotangent that does not depend on y, every
+    input's gradient equals the chunked reference's."""
+    args = _ssd_operands(2, s, hh, p, n, g, seed=3)
+    w = jax.random.normal(jax.random.PRNGKey(4), args[0].shape)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a, chunk=16) * w)
+
+    kernel = functools.partial(ops.ssd_scan, interpret=True)
+    got = jax.grad(loss(kernel), argnums=range(6))(*args)
+    want = jax.grad(loss(ref.ssd_scan_bshp_chunked_ref),
+                    argnums=range(6))(*args)
+    for gk, gr in zip(got, want):
+        np.testing.assert_allclose(np.asarray(gk), np.asarray(gr),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_ssd_layout_at_granite_shape():
+    """Granite 4.0-H Micro's round call (2 clients x 2 x 512 tokens, 64
+    heads of 64, one group of state 128, chunk 256): blocks of 32 heads
+    (2048 lanes), two a chunk, B and C read by group and never
+    repeated."""
+    lay = ssd_layout(4, 512, 64, 64, 1, 128, jnp.bfloat16, chunk=256)
+    assert lay.grid == (4, 2, 2)
+    assert lay.operands["x"].shape == (4, 512, 64 * 64)
+    assert lay.operands["x"].block == (1, 256, 32 * 64)
+    assert lay.outputs["y"].block == (1, 256, 32 * 64)
+    assert lay.operands["dt"].block == (1, 256, 64)
+    for name in ("b", "c"):
+        assert lay.operands[name].shape == (4, 512, 128)
+        assert lay.operands[name].block == (1, 256, 128)
+    assert [ssd_block_group(hi, 32, 64) for hi in range(2)] == [0, 0]
+    assert lint_layout(lay) == []
+
+
+@pytest.mark.parametrize("h,p,g,n", [
+    (64, 64, 1, 128),   # granite-4.0-h-micro
+    (80, 64, 1, 128),   # mamba2-2.7b
+    (128, 64, 1, 16),   # jamba-v0.1's Mamba widths
+    (8, 32, 1, 16),     # the reduced configs
+    (64, 64, 8, 128),   # 8 heads a group
+    (64, 64, 64, 128),  # G = H
+    (64, 16, 4, 64),    # hb·P reaches 128 only at 8 of a group's 16
+    (4, 16, 4, 8),      # G = H below a lane tile: one head a step
+    (6, 32, 2, 16),     # 3 heads a group: no divisor fills 128 lanes
+])
+@pytest.mark.parametrize("s,chunk", [(512, 256), (2048, 256), (50, 16)])
+def test_ssd_layout_derives_the_head_block_from_the_shape(h, p, g, n, s,
+                                                          chunk):
+    """The head block divides a group's heads (a block never straddles
+    two groups) and fills whole 128-lane tiles in the model layout;
+    where no divisor can, a step takes one head, head-major. Every
+    layout passes the L003 lint."""
+    hpg = h // g
+    can_fill = any(hpg % k == 0 and (k * p % 128 == 0 or k == h)
+                   for k in range(1, hpg + 1))
+    for dtype in (jnp.float32, jnp.bfloat16):
+        lay = ssd_layout(2, s, h, p, g, n, dtype, chunk=chunk)
+        x = lay.operands["x"]
+        hb = h // lay.grid[1]
+        assert hpg % hb == 0
+        if can_fill:
+            assert x.shape[1:] == (x.shape[1], h * p)
+            assert x.block[2] == hb * p and (hb * p % 128 == 0 or hb == h)
+        else:
+            assert hb == 1 and x.shape == (2, h, x.shape[2], p)
+        assert lay.grid == (2, h // hb, x.shape[-2] // x.block[-2])
+        for hi in range(h // hb):
+            assert {k // hpg for k in range(hi * hb, (hi + 1) * hb)} == \
+                {ssd_block_group(hi, hb, hpg)}
+        assert lint_layout(lay) == [], lay
+
+
+@pytest.mark.parametrize("parts", [SPLIT, 3])
+def test_ssd_split_terms_sum_to_the_float32_operand(parts):
+    """The kernel puts a float32 matmul operand through the MXU as
+    bfloat16 terms: three sum to it exactly (the exact selections of dt
+    and its prefix sums), the kernel's ``SPLIT`` keep 16 bits of it, from
+    1e-6 to 1e6."""
+    v = jax.random.normal(jax.random.PRNGKey(0), (256, 512)) \
+        * jnp.logspace(-6, 6, 512)
+    terms = _split(v, parts)
+    assert [t.dtype for t in terms] == [jnp.bfloat16] * parts
+    total = terms[0].astype(jnp.float32)
+    for t in terms[1:]:
+        total = total + t.astype(jnp.float32)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(v),
+                               rtol=0 if parts == 3 else 2.0 ** -16, atol=0)
 
 
 def test_ssd_model_layout_chunked_matches_sequential_oracle():

@@ -11,8 +11,9 @@ it, which is the name a device trace shows.
 
 Widths: qwen2-7b (d_model 3584, 28 query / 4 KV heads of 128, d_ff
 18944) for the attention, LoRA and decode kernels; mamba2-2.7b (80 heads
-of 64, state 128, chunk 256) for the SSD scan; granite-moe-1b-a400m
-(32 experts, d_model 1024, expert d_ff 512) for the grouped GEMM.
+of 64, state 128, chunk 256) and granite-4.0-h-micro (64 heads) for the
+SSD scan; granite-moe-1b-a400m (32 experts, d_model 1024, expert d_ff
+512) for the grouped GEMM.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and the test
@@ -103,12 +104,17 @@ def test_flash_decode_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_ssd_scan_compiles(one_chip):
-    b, s, h, p, n = 1, 2048, 80, 64, 128
+@pytest.mark.parametrize("b,s,h,g", [
+    (1, 2048, 80, 1),   # mamba2-2.7b: blocks of 16 heads, 5 a chunk
+    (4, 512, 64, 1),    # granite-4.0-h-micro's round call: 2 of 32
+    (1, 2048, 64, 64),  # G = H: one head a step, head-major
+])
+def test_ssd_scan_compiles(one_chip, b, s, h, g):
+    p, n = 64, 128
     text = _compile_text(one_chip, "ssd_scan",
                          [((b, s, h, p), BF16), ((b, s, h), BF16),
-                          ((h,), jnp.float32), ((b, s, 1, n), BF16),
-                          ((b, s, 1, n), BF16), ((h,), jnp.float32)],
+                          ((h,), jnp.float32), ((b, s, g, n), BF16),
+                          ((b, s, g, n), BF16), ((h,), jnp.float32)],
                          chunk=256)
     assert "tpu_custom_call" in text
 
